@@ -1,5 +1,6 @@
-"""Kernel microbenchmarks: interpret-mode wall time (CPU, correctness-scale)
-plus the analytic VMEM working set per BlockSpec tile — the quantity that
+"""Kernel microbenchmarks: wall time as the backend runs the kernels
+(interpret mode on the CPU at correctness scale, compiled on a TPU) plus
+the analytic VMEM working set per BlockSpec tile — the quantity that
 determines whether a tile choice fits v5e VMEM (128 MiB/core budget split
 across buffers).  Prints name,us_per_call,derived CSV.
 

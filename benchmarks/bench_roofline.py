@@ -18,12 +18,12 @@ import glob
 import json
 import os
 
+from repro.roofline.peaks import peaks
+
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
-PEAK = 197e12
-HBM = 819e9
-ICI = 50e9
-HBM_CAP = 16e9
+# the dry-run compiles for the production TPU v5e pod
+CHIP = peaks("TPU v5 lite")
 
 
 def model_flops(arch: str, shape_kind: str, tokens: int) -> float:
@@ -60,9 +60,9 @@ def roofline_row(rec):
     probe = rec.get("probe", {}).get("totals")
     if probe is None:
         return None
-    t_comp = probe["flops"] / PEAK
-    t_mem = probe["bytes"] / HBM
-    t_coll = probe["coll"] / ICI
+    t_comp = probe["flops"] / CHIP["flops_bf16"]
+    t_mem = probe["bytes"] / CHIP["hbm_bw"]
+    t_coll = probe["coll"] / CHIP["ici_bw_per_link"]
     terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["kind"], tokens_of(rec["shape"]))
@@ -70,12 +70,12 @@ def roofline_row(rec):
     mem = rec["memory"]
     # (t_mem_lb computed below from the same buffer stats)
     hbm_used = (mem["argument_bytes"] + mem["temp_bytes"]
-                + mem["output_bytes"]) / HBM_CAP
+                + mem["output_bytes"]) / CHIP["hbm_bytes"]
     # memory-traffic LOWER bound from real buffer sizes (args read once,
     # outputs written once, temps written+read) — brackets the op-level
     # upper bound in t_memory_s
     t_mem_lb = (mem["argument_bytes"] + mem["output_bytes"]
-                + 2 * mem["temp_bytes"]) / HBM
+                + 2 * mem["temp_bytes"]) / CHIP["hbm_bw"]
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "variant": rec.get("variant", "baseline"),

@@ -30,7 +30,8 @@ Figures reproduced (CPU-scale analog of CIFAR-10/ImageNet ResNet-3-stage):
            floor, ragged length-bucket batching under 2x overload, the
            end-to-end Pallas-backed run on the real anytime classifier
            (fused exit-confidence bit-for-bit vs the unfused reference,
-           ragged decode batching bitwise vs singletons)  [extension]
+           ragged decode batching vs singletons: preds exact, hidden
+           states to float32 rounding)  [extension]
   plane    the durable request plane (repro.serving.plane): DRR vs FIFO
            tenant fairness under skewed overload, idempotent journaled
            submission, and bit-for-bit mid-stream crash recovery
@@ -419,9 +420,8 @@ def fig_sharded(conf, correct, dps=(1, 2, 4), n_requests=900,
     **End-to-end 1x1-mesh run** — ``ServeSpec(executor="device-sharded")``
     on the real anytime classifier, driven by the ``steady`` traffic
     scenario through the registry (``repro.launch.serve`` registers the
-    executor from outside the serving package).  On this host's
-    single-device fallback mesh the results must match
-    ``device-batched`` **bit-for-bit**; the per-request hidden-state
+    executor from outside the serving package).  On the 1x1 mesh the
+    results must match ``device-batched`` **bit-for-bit**; the per-request hidden-state
     cache must be fully evicted at drain.  This is the CI leg: the full
     sharded code path (mesh build, sharding constraints, dp-divisible
     buckets, state cache) runs everywhere.
@@ -480,7 +480,7 @@ def _sharded_e2e(rows, n_requests=40, seed=0):
                      "marginal": 0.25}
     runs = {}
     for ex, ea in (("device-batched", {}),
-                   ("device-sharded", {"dp": 2, "tp": 1})):
+                   ("device-sharded", {"dp": 1, "tp": 1})):
         spec = dataclasses.replace(base, executor=ex, executor_args=ea)
         svc = Service.from_spec(
             spec, cfg=cfg, params=params, n_samples=len(pool), labels=labels,
@@ -495,9 +495,8 @@ def _sharded_e2e(rows, n_requests=40, seed=0):
     sx = runs["device-sharded"][0].executor
     parity = key(runs["device-batched"][1].per_request) \
         == key(runs["device-sharded"][1].per_request)
-    print(f"sharded,e2e,parity,mesh={sx.dp}x{sx.tp},"
-          f"fallback={sx.fallback},bitwise={parity}")
-    return dict(mesh=[sx.dp, sx.tp], fallback=sx.fallback, parity=parity,
+    print(f"sharded,e2e,parity,mesh={sx.dp}x{sx.tp},bitwise={parity}")
+    return dict(mesh=[sx.dp, sx.tp], parity=parity,
                 cache=sx.cache_stats(), n_requests=n_requests,
                 served=runs["device-sharded"][1].n_requests)
 
@@ -516,7 +515,7 @@ def sharded_claims(modeled, e2e):
         and e2e["cache"]["evictions"] >= e2e["n_requests"]
     # parity is bitwise only where both runs use one device — a real
     # multi-device mesh reorders float reductions
-    parity_req = (not e2e["fallback"]) and e2e["mesh"] != [1, 1]
+    parity_req = e2e["mesh"] != [1, 1]
     claims = {
         "sharded_collective_s": SHARDED_COLLECTIVE,
         "sharded_goodput_by_dp": {str(d): round(g[d], 1) for d in dps},
@@ -566,7 +565,8 @@ def fig_kernel(conf, correct, async_comp, *, n_requests=1200,
     exit-confidence epilogue must be *bit-for-bit* the unfused reference
     in interpret mode, a ``pipeline_depth=3`` run must stack device
     windows and drain its hidden-state cache, and co-batched ragged
-    decode must be bitwise equal to singleton decode.
+    decode must match singleton decode (preds exact, hidden states to
+    float32 rounding).
     """
     from repro.serving.batch.time_model import LengthBucketTimeModel
     from repro.serving.traffic import scenario_spec
@@ -691,17 +691,19 @@ def _kernel_e2e(rows, n_requests=40, seed=0):
 
 def _kernel_decode_check():
     """Ragged decode batching exactness: co-batched decode at ragged
-    cache positions through the Pallas route must be bitwise equal to
-    running each request alone (the per-row slot-position map; the
-    legacy jnp route shares row 0's and is only approximately equal)."""
+    cache positions through the Pallas route must be bitwise equal to a
+    same-shape batch of each request alone, and give each request the
+    prediction it gets at batch 1 with hidden states, confidences and
+    cache rows equal to float32 rounding (1e-5 of the hidden scale — XLA
+    does not promise batch-shape-invariant bits), thanks to the per-row
+    slot-position map; the legacy jnp route shares row 0's and is only
+    approximately equal."""
     import jax
-    import jax.numpy as jnp
 
     from repro.configs.base import ModelConfig
-    from repro.launch.kernel import KernelDecodeStageFns
+    from repro.launch.kernel import KernelDecodeStageFns, ragged_decode_check
     from repro.launch.mesh import make_serving_mesh
-    from repro.models import (ParallelCtx, concat_decode_caches,
-                              init_decode_cache, init_params)
+    from repro.models import ParallelCtx, init_params
     cfg = ModelConfig(name="bench-decode", arch_type="dense", source="bench",
                       num_layers=2, d_model=32, num_heads=2, num_kv_heads=2,
                       head_dim=16, d_ff=64, vocab_size=16, period=("attn",),
@@ -711,39 +713,16 @@ def _kernel_decode_check():
     params = init_params(cfg, jax.random.PRNGKey(0))
     ctx = ParallelCtx(mesh=make_serving_mesh(1, 1), decode_attn="kernel")
     fns = KernelDecodeStageFns(cfg, (1, 2, 4), ctx)
-    rng = np.random.default_rng(0)
-    S, positions, states = 8, [2, 5, 7], []
-    for pos in positions:           # warm each request's cache to pos
-        cache = init_decode_cache(cfg, 1, S)
-        for p in range(pos):
-            h = jnp.array([int(rng.integers(cfg.vocab_size))], jnp.int32)
-            for s in range(cfg.num_stages):
-                h, c, _pred, _conf = fns.fn(s)(
-                    params, h, cache[s], jnp.full((1,), p, jnp.int32))
-                cache[s] = c
-        states.append({"h": jnp.array([int(rng.integers(cfg.vocab_size))],
-                                      jnp.int32),
-                       "cache": cache,
-                       "cur_pos": jnp.full((1,), pos, jnp.int32)})
-    h_b = jnp.concatenate([st["h"] for st in states])
-    cur_b = jnp.concatenate([st["cur_pos"] for st in states])
-    outs_b = []
-    for s in range(cfg.num_stages):
-        cache_b = concat_decode_caches([st["cache"][s] for st in states])
-        h_b, _c, pred_b, conf_b = fns.fn(s)(params, h_b, cache_b, cur_b)
-        outs_b.append((h_b, pred_b, conf_b))
-    bitwise = True
-    for i, st in enumerate(states):
-        h = st["h"]
-        for s in range(cfg.num_stages):
-            h, _c, pred, conf = fns.fn(s)(params, h, st["cache"][s],
-                                          st["cur_pos"])
-            h_bs, pred_b, conf_b = outs_b[s]
-            bitwise &= np.array_equal(np.asarray(h), np.asarray(h_bs[i:i + 1]))
-            bitwise &= (int(pred[0]) == int(pred_b[i])
-                        and float(conf[0]) == float(conf_b[i]))
-    print(f"kernel,decode,ragged,positions={positions},bitwise={bitwise}")
-    return dict(bitwise=bool(bitwise), positions=positions)
+    positions = [2, 5, 7]
+    r = ragged_decode_check(fns, params, positions, 8, seed=0)
+    tol = 1e-5 * max(1.0, r["h_scale"])
+    exact = bool(r["same_shape_equal"] and r["pred_equal"]
+                 and r["h_err"] <= tol
+                 and r["cache_err"] <= tol and r["conf_err"] <= 1e-6)
+    print(f"kernel,decode,ragged,positions={positions},exact={exact},"
+          f"h_err={r['h_err']:.3g},conf_err={r['conf_err']:.3g}")
+    return dict(exact=exact, positions=positions, h_err=r["h_err"],
+                conf_err=r["conf_err"])
 
 
 def kernel_claims(deep, ragged, e2e, async_comp):
@@ -752,7 +731,8 @@ def kernel_claims(deep, ragged, e2e, async_comp):
     accuracy/miss equal-or-better than synchronous dispatch; the fused
     exit epilogue is bit-for-bit the unfused reference; ragged traffic
     batched via length buckets keeps admitted misses < 1%; co-batched
-    ragged decode is bitwise equal to singleton decode."""
+    ragged decode matches singleton decode (preds exact, hidden states
+    to float32 rounding)."""
     floor = min(c["host_frac_async"] for c in async_comp.values())
     qualifying = {}
     for (k, name), d in deep.items():
@@ -783,12 +763,12 @@ def kernel_claims(deep, ragged, e2e, async_comp):
         "kernel_e2e_times": {"host_time": e2e["host_time"],
                              "device_time": e2e["device_time"]},
         "kernel_e2e_cache": e2e["cache"],
-        "kernel_decode_ragged_bitwise": bool(dec["bitwise"]),
+        "kernel_decode_ragged_exact": bool(dec["exact"]),
         "kernel_claim_met": bool(
             full_ks and ragged["admitted_miss"] < 0.01
             and ragged["rejected"] > 0 and e2e["parity"]
             and e2e["conf_close"] and e2e["fused_bitwise"]
-            and dec["bitwise"] and e2e["cache"]["live"] == 0
+            and dec["exact"] and e2e["cache"]["live"] == 0
             and e2e["served"] == e2e["n_requests"]),
     }
     print("KERNEL CLAIMS:", claims)

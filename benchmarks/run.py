@@ -7,12 +7,13 @@ block covers the paper figures plus the ``batch`` / ``async`` /
 ``traffic`` / ``sharded`` serving-extension figures and records their
 claims in ``artifacts/scheduling_results.json``.
 
-Prints ``name,us_per_call,derived`` style CSV blocks per bench.
+Prints ``name,us_per_call,derived`` style CSV blocks per bench.  A phase
+that fails (a missing artifact included) ends the run with its exception
+and a non-zero exit code.
 """
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 
@@ -32,10 +33,7 @@ def main(argv=None):
         from benchmarks import bench_scheduling
         if args.quick:
             bench_scheduling.DEFAULTS["n_requests"] = 200
-        try:
-            bench_scheduling.main()
-        except FileNotFoundError as e:
-            print(f"SKIP scheduling: {e}", file=sys.stderr)
+        bench_scheduling.main()
     if args.only in (None, "kernels"):
         print("== kernel microbenchmarks ==")
         from benchmarks import bench_kernels
@@ -43,17 +41,11 @@ def main(argv=None):
     if args.only in (None, "ablations"):
         print("== scheduler ablations (beyond paper) ==")
         from benchmarks import bench_ablations
-        try:
-            bench_ablations.main()
-        except FileNotFoundError as e:
-            print(f"SKIP ablations: {e}", file=sys.stderr)
+        bench_ablations.main()
     if args.only in (None, "roofline"):
         print("== roofline table (from dry-run artifacts) ==")
         from benchmarks import bench_roofline
-        try:
-            bench_roofline.main()
-        except Exception as e:  # noqa: BLE001
-            print(f"SKIP roofline: {e}", file=sys.stderr)
+        bench_roofline.main()
     print(f"total {time.time()-t0:.0f}s")
 
 

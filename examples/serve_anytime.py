@@ -10,10 +10,11 @@ Every engine is built through the public serving API: a declarative
 ``ServeSpec`` names the policy / executor / clock / source by registry key
 (``device-single`` = unbatched per-stage dispatch, ``device-batched`` =
 continuous micro-batching, ``pipeline_depth=2`` = pipelined async
-dispatch, ``device-sharded`` = the batched engine across a ``(dp, tp)``
-mesh with a 1x1 fallback on single-device hosts, ``device-kernel`` with
+dispatch, ``device-sharded`` = the batched engine across a ``(dp, 1)``
+mesh — ``--dp`` must not exceed the host's devices, ``device-kernel`` with
 ``--kernels`` = Pallas stage bodies with the fused exit-confidence
-epilogue at ``pipeline_depth=3``), and
+epilogue at ``pipeline_depth=3``, compiled on a TPU and interpreted on the
+CPU), and
 ``repro.serving.Service`` owns the engine lifecycle; the model params /
 stage fns / profiled time model ride along as resources.
 
@@ -40,6 +41,7 @@ import numpy as np
 import repro.launch.serve  # noqa: F401 — registers device-sharded
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (BatchedStageFns, ServeSpec, Service,
                            closed_loop_stream, make_stage_fns,
@@ -60,10 +62,9 @@ def main(argv=None):
     ap.add_argument("--buckets", type=int, nargs="+", default=[1, 2, 4, 8],
                     help="pre-compiled batch-size buckets for the batched "
                          "engine")
-    ap.add_argument("--dp", type=int, default=2,
+    ap.add_argument("--dp", type=int, default=1,
                     help="data-parallel ways for the device-sharded engine "
-                         "(falls back to a 1x1 mesh when the host has "
-                         "fewer devices)")
+                         "(the host must have that many devices)")
     ap.add_argument("--kernels", action="store_true",
                     help="also run the kernel-backed fast path (executor "
                          "'device-kernel': Pallas stage bodies, fused "
@@ -75,6 +76,7 @@ def main(argv=None):
     if args.smoke:
         args.requests, args.clients, args.buckets = 8, 2, [1, 2]
     n_runs = 5 if args.smoke else 60
+    enable_compile_cache()
 
     cfg = get_config("anytime-classifier")
     ckpt_path = os.path.join(ART, "anytime_classifier.ckpt")
@@ -178,10 +180,10 @@ def main(argv=None):
         svc.run(stream())
         results[f"pipelined-{name}"] = report(f"pipelined-{name}", svc)
     # sharded across a (dp, tp) mesh (executor "device-sharded", registered
-    # by repro.launch.serve from outside the serving package); on a
-    # single-device host the mesh falls back to 1x1, so this leg exercises
-    # the full sharded path — mesh build, sharding constraints,
-    # dp-divisible buckets, device-resident state cache — everywhere
+    # by repro.launch.serve from outside the serving package); at the
+    # default --dp 1 this leg exercises the full sharded path — mesh
+    # build, sharding constraints, dp-divisible buckets, device-resident
+    # state cache — on any single-device host
     name, pargs = POLICIES[0]
     svc = Service.from_spec(spec_for(name, pargs, batched=True, sharded=True),
                             cfg=cfg, params=params, time_model=time_model)

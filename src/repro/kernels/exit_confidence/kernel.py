@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -69,7 +71,7 @@ def _exit_conf_kernel(h_ref, scale_ref, w_ref, o_ref, m_ref, l_ref, a_ref,
 
 def exit_confidence(h, scale, w_out, *, eps: float = 1e-6,
                     temperature: float = 1.0, block_rows: int = 8,
-                    block_v: int = 512, interpret: bool = True):
+                    block_v: int = 512, interpret: bool | None = None):
     """h: (N, d) hidden rows; scale: (d,) RMSNorm scale; w_out: (d, V).
 
     Returns (conf (N,), pred (N,) int32, max_logit (N,), lse (N,)).
@@ -104,7 +106,7 @@ def exit_confidence(h, scale, w_out, *, eps: float = 1e-6,
             pltpu.VMEM((block_rows,), jnp.float32),   # sum exp(l - m)
             pltpu.VMEM((block_rows,), jnp.int32),     # running argmax
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(h, scale, w_out)
     out = out[:N]
     return out[:, 0], out[:, 1].astype(jnp.int32), out[:, 2], out[:, 3]

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -88,7 +90,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     softmax_scale=None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool | None = None):
     """q: (B, H, S, dh); k, v: (B, KV, S, dh).  Returns (B, H, S, dh).
 
     H must be a multiple of KV (GQA).  S is padded internally to block size.
@@ -136,7 +138,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((block_q,), jnp.float32),           # normalizer l
             pltpu.VMEM((block_q, dh), jnp.float32),        # fp32 accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(B * H, Sp, dh),
       k.reshape(B * KV, Sp, dh),
       v.reshape(B * KV, Sp, dh))

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -70,7 +72,7 @@ def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, c0_ref, n0_ref,
     m1_ref[0, ...] = jnp.array([bL + gL], jnp.float32)
 
 
-def mlstm_chunk(q, k, v, i_pre, f_pre, C0, n0, m0, *, interpret: bool = True):
+def mlstm_chunk(q, k, v, i_pre, f_pre, C0, n0, m0, *, interpret: bool | None = None):
     """One chunk for all (batch, head) tiles.
 
     q,k,v: (B,H,L,dh); i_pre,f_pre: (B,H,L); C0: (B,H,dh,dh);
@@ -99,7 +101,7 @@ def mlstm_chunk(q, k, v, i_pre, f_pre, C0, n0, m0, *, interpret: bool = True):
                    pl.BlockSpec((1, dh), lambda i: (i, 0)),
                    pl.BlockSpec((1, 1), lambda i: (i, 0))),
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(BH, L, dh), k.reshape(BH, L, dh), v.reshape(BH, L, dh),
       i_pre.reshape(BH, L), f_pre.reshape(BH, L),
       C0.astype(jnp.float32).reshape(BH, dh, dh),

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
@@ -21,7 +23,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps):
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool | None = None):
     """x: (N, d); scale: (d,) -> (N, d)."""
     N, d = x.shape
     block_rows = min(block_rows, N)
@@ -34,6 +36,6 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp, scale)
     return out[:N]

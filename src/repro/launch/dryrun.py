@@ -37,7 +37,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
               probes: bool = True, q_chunk: int = 1024):
     from repro.configs import get_config, get_shape
     from repro.launch import steps as S
-    from repro.launch.mesh import make_production_mesh, set_mesh
+    from repro.launch.mesh import make_production_mesh
     from repro.launch.shardings import (batch_shardings, cache_shardings,
                                         decode_weight_layout,
                                         expert_templates_for, opt_shardings,
@@ -66,7 +66,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
     p_sh = param_shardings(mesh, params, etpl, layout=layout)
     specs = S.input_specs(cfg, shape)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             n_micro = S.pick_microbatches(cfg, ctx, shape.global_batch,
                                           shape.seq_len)
@@ -114,8 +114,6 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, *,
             "code_bytes": int(ma.generated_code_size_in_bytes),
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):     # jax < 0.6: one dict per program
-            ca = ca[0] if ca else {}
         rec["cost"] = {k: float(v) for k, v in ca.items()
                        if isinstance(v, (int, float)) and
                        k in ("flops", "bytes accessed")}

@@ -15,7 +15,9 @@ executor contract unchanged:
   the kernel and confidence never round-trips to host between stages.
   With a single vocab block the online pass folds exactly once, so in
   interpret mode ``conf``/``pred`` are bit-for-bit equal to the unfused
-  reference (:func:`repro.models.exits.exit_stats_unfused`).
+  reference (:func:`repro.models.exits.exit_stats_unfused`).  Kernels are
+  compiled on an accelerator and interpreted on the CPU
+  (:func:`repro.kernels.resolve_interpret`).
 * **Ragged decode batching** — ``mode="decode"`` dispatches
   :func:`repro.models.stage_decode_step` with
   ``ParallelCtx(decode_attn="kernel")``: attention reads each request's
@@ -50,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import resolve_interpret
 from repro.models import (ParallelCtx, concat_decode_caches, exit_rows,
                           exit_stats_fused, slice_decode_cache,
                           stage_decode_step, stage_trunk)
@@ -100,13 +103,13 @@ class KernelStageFns(BatchedStageFns):
     the audio codebook head has no fused kernel.
     """
 
-    def __init__(self, cfg, buckets, *, interpret: bool = True,
+    def __init__(self, cfg, buckets, *, interpret: bool | None = None,
                  block_rows: int = 8, block_v: int = 512):
         if cfg.modality == "audio_stub":
             raise ValueError("device-kernel: the audio codebook exit head "
                              "has no fused kernel; use device-batched")
         super().__init__(cfg, buckets)
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         self.block_rows = int(block_rows)
         self.block_v = int(block_v)
 
@@ -146,7 +149,7 @@ class KernelDecodeStageFns:
     """
 
     def __init__(self, cfg, buckets, ctx: ParallelCtx, *,
-                 interpret: bool = True, block_rows: int = 8,
+                 interpret: bool | None = None, block_rows: int = 8,
                  block_v: int = 512):
         if cfg.modality == "audio_stub":
             raise ValueError("device-kernel: the audio codebook exit head "
@@ -154,7 +157,7 @@ class KernelDecodeStageFns:
         self.cfg = cfg
         self.buckets = tuple(sorted(buckets))
         self.ctx = ctx
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         self.block_rows = int(block_rows)
         self.block_v = int(block_v)
         self._fns = {}
@@ -187,6 +190,86 @@ class KernelDecodeStageFns:
                 out = self.fn(s)(params, h, cache, cur)
                 jax.block_until_ready(out[0])
                 h = out[0]
+
+
+def ragged_decode_check(fns: KernelDecodeStageFns, params, positions,
+                        slots: int, *, seed: int = 0) -> dict:
+    """Co-batched decode at ragged cache positions vs each request alone.
+
+    One single-request cache per entry of ``positions`` (all with
+    ``slots`` KV slots, i.e. one length bucket) is warmed to that position
+    with random tokens; then one decode step of every stage runs over the
+    ragged batch, over each request alone, and over a batch of the same
+    shape holding copies of one request (what bucket padding builds).
+
+    ``same_shape_equal``: the ragged batch's row of each request is
+    bit-for-bit the same-shape run of that request — no row reads another
+    row's cache or positions.  Against the request alone, batch shape
+    changes XLA's float rounding, so the check reports whether every
+    ``pred`` agreed, the largest ``|h_batched - h_alone|`` (``h_err``)
+    beside the largest ``|h_alone|`` (``h_scale``), and the largest
+    ``conf`` and cache-row differences, for callers to hold to the
+    tolerance of their dtype and backend.
+    """
+    from repro.models import init_decode_cache
+    cfg = fns.cfg
+    rng = np.random.default_rng(seed)
+
+    def token():
+        return jnp.array([int(rng.integers(cfg.vocab_size))], jnp.int32)
+
+    def step(states):
+        """One decode step of every stage over ``states`` as one batch:
+        per stage ``(h, cache, pred, conf)``."""
+        h = jnp.concatenate([st["h"] for st in states])
+        cur = jnp.concatenate([st["cur_pos"] for st in states])
+        outs = []
+        for s in range(cfg.num_stages):
+            cache = concat_decode_caches([st["cache"][s] for st in states])
+            h, cache, pred, conf = fns.fn(s)(params, h, cache, cur)
+            outs.append((h, cache, pred, conf))
+        return outs
+
+    states = []
+    for pos in positions:
+        cache = init_decode_cache(cfg, 1, slots)
+        for p in range(pos):
+            h = token()
+            for s in range(cfg.num_stages):
+                h, cache[s], _pred, _conf = fns.fn(s)(
+                    params, h, cache[s], jnp.full((1,), p, jnp.int32))
+        states.append({"h": token(), "cache": cache,
+                       "cur_pos": jnp.full((1,), pos, jnp.int32)})
+    batched = step(states)
+
+    def rows(out, i):
+        h, cache, pred, conf = out
+        return [h[i:i + 1], pred[i:i + 1], conf[i:i + 1],
+                *jax.tree.leaves(slice_decode_cache(cache, i))]
+
+    def err(a, b):
+        return float(np.max(np.abs(np.asarray(a, np.float32)
+                                   - np.asarray(b, np.float32))))
+
+    out = dict(same_shape_equal=True, pred_equal=True, h_err=0.0,
+               h_scale=0.0, conf_err=0.0, cache_err=0.0,
+               positions=list(positions))
+    for i, st in enumerate(states):
+        same = step([st] * len(states))
+        alone = step([st])
+        for s in range(cfg.num_stages):
+            b_rows = rows(batched[s], i)
+            out["same_shape_equal"] &= all(
+                np.array_equal(np.asarray(a), np.asarray(b))
+                for a, b in zip(b_rows, rows(same[s], 0)))
+            h, pred, conf, *cache = rows(alone[s], 0)
+            out["pred_equal"] &= int(pred[0]) == int(b_rows[1][0])
+            out["h_err"] = max(out["h_err"], err(h, b_rows[0]))
+            out["h_scale"] = max(out["h_scale"], err(h, 0))
+            out["conf_err"] = max(out["conf_err"], err(conf, b_rows[2]))
+            for a, b in zip(cache, b_rows[3:]):
+                out["cache_err"] = max(out["cache_err"], err(a, b))
+    return out
 
 
 class KernelDeviceExecutor(DeviceExecutor):
@@ -257,8 +340,9 @@ def build_kernel_executor(args: dict, ctx):
     * ``mode`` — ``"classifier"`` (default: fused-exit ``stage_trunk``
       over hidden pytrees) or ``"decode"`` (ragged decode batching over
       per-request KV caches through the Pallas decode kernel).
-    * ``interpret`` — run the Pallas kernels in interpret mode (default
-      True: bit-exact on CPU CI; set False on a real TPU backend).
+    * ``interpret`` — optional; ``True`` restates interpret mode on a CPU
+      backend and raises on an accelerator, where kernels always compile
+      (default: decided by :func:`repro.kernels.resolve_interpret`).
     * ``block_rows`` / ``block_v`` — fused exit kernel tile sizes.
     * ``len_buckets`` — optional ascending lengths; refines
       ``ctx.time_model`` via :func:`length_bucketed_time_model` so the
@@ -271,8 +355,8 @@ def build_kernel_executor(args: dict, ctx):
     """
     cfg, params = ctx.resources["cfg"], ctx.resources["params"]
     mode = args.get("mode", "classifier")
-    interpret = bool(args.get("interpret", True))
-    kw = dict(interpret=interpret, block_rows=int(args.get("block_rows", 8)),
+    kw = dict(interpret=args.get("interpret"),
+              block_rows=int(args.get("block_rows", 8)),
               block_v=int(args.get("block_v", 512)))
     lbs = args.get("len_buckets")
     if lbs:

@@ -10,23 +10,9 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh):
-    """``jax.set_mesh(mesh)`` where it exists (jax >= 0.6); on older jax
-    the ``Mesh`` object itself is the context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def _mesh(shape, axes):
-    # jax < 0.6 has no jax.sharding.AxisType (Auto is that era's default);
-    # jax < 0.4.35 has no jax.make_mesh at all
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -40,34 +26,21 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 4):
     return _mesh((n_data, n_model), ("data", "model"))
 
 
-def make_serving_mesh(dp: int = 1, tp: int = 1, *,
-                      axes=("data", "model"), require: bool = False):
+def make_serving_mesh(dp: int = 1, tp: int = 1, *, axes=("data", "model")):
     """A ``(dp, tp)`` serving mesh: data-parallel batch rows over
     ``axes[0]``, tensor-parallel weights within a stage over ``axes[1]``.
 
     The ``device-sharded`` executor (registered by :mod:`repro.launch.serve`,
     built in :mod:`repro.launch.sharded`) runs its stage fns over this mesh.
-    When the host has fewer than ``dp * tp`` devices the mesh **falls back
-    to 1x1** so the same ServeSpec runs everywhere (single-device CI
-    exercises the full sharded code path as a degenerate mesh); pass
-    ``require=True`` to raise instead — a production launcher should fail
-    loudly, not silently serve at 1/dp of the provisioned capacity.
+    A mesh the host cannot give raises: the mesh always holds exactly
+    ``dp * tp`` devices, so a sharded result is never a silent single-device
+    one.  One-device callers ask for ``dp=1, tp=1``.
     """
     dp, tp = int(dp), int(tp)
     if dp < 1 or tp < 1:
         raise ValueError(f"dp and tp must be >= 1, got dp={dp} tp={tp}")
     n = len(jax.devices())
     if dp * tp > n:
-        if require:
-            raise ValueError(f"serving mesh needs dp*tp={dp * tp} devices, "
-                             f"host has {n}")
-        dp = tp = 1
+        raise ValueError(f"serving mesh needs dp*tp={dp * tp} devices, "
+                         f"host has {n}")
     return _mesh((dp, tp), tuple(axes))
-
-
-# TPU v5e hardware model (roofline constants, per chip)
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW = 50e9                     # B/s per link (intra-pod)
-DCN_BW = 25e9                     # B/s (pod axis)
-HBM_BYTES = 16e9                  # v5e HBM capacity

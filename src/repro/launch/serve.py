@@ -22,17 +22,24 @@ the registry's extension points (no core module touched):
   issues it as the next request;
 * executor ``device-sharded`` (:mod:`repro.launch.sharded`) — the batched
   classifier engine with its stage fns sharded over a ``(dp, tp)`` mesh
-  from :func:`repro.launch.mesh.make_serving_mesh`; falls back to a 1x1
-  mesh on single-device hosts so the same ServeSpec runs everywhere;
+  from :func:`repro.launch.mesh.make_serving_mesh`; a mesh larger than
+  the host raises, so one-device hosts run ``dp=1, tp=1``;
 * executor ``device-kernel`` (:mod:`repro.launch.kernel`) — Pallas-backed
   stage fns: fused exit-confidence epilogue (no logits round-trip) and
   ragged decode batching over per-request KV caches through the decode
-  kernel, with ``(stage, batch-bucket, len-bucket)`` WCET pricing.
+  kernel, with ``(stage, batch-bucket, len-bucket)`` WCET pricing; the
+  kernels compile on a TPU and run in interpret mode only on a CPU
+  backend (:func:`repro.kernels.resolve_interpret`).
 
 ``--dry-run`` validates the spec against the registry and prints it as
 JSON without touching the model (the CI examples-smoke job).
 
+The registered config is served at its own width (qwen3-4b: 36 layers,
+d_model 2560, bf16 — about 8.8 GB of params, one TPU v5e chip);
+``--reduced`` serves the CPU-sized variant:
+
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --tokens 24
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced
 """
 from __future__ import annotations
 
@@ -167,7 +174,7 @@ def _make_device_sharded(args, ctx):
     """``device-batched`` across a ``(dp, tp)`` mesh: batch rows sharded
     over ``dp``, stage weights over ``tp``, per-request hidden state cached
     on device between stage dispatches.  args:
-    ``{"dp": ..., "tp": ..., "mesh": [dp_axis, tp_axis], "require": ...,
+    ``{"dp": ..., "tp": ..., "mesh": [dp_axis, tp_axis],
     "collective": ...}`` (see :func:`repro.launch.sharded.
     build_sharded_executor`); resources: ``cfg``, ``params``, optionally
     ``stage_fns`` / ``mesh``."""
@@ -260,8 +267,13 @@ def build_spec(args, n_stages: int) -> ServeSpec:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the config's reduced (CPU-sized, float32) "
+                         "variant instead of its registered width")
     ap.add_argument("--tokens", type=int, default=24)
     ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the random params")
     ap.add_argument("--conf-target", type=float, default=0.7)
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--pipeline", action="store_true",
@@ -274,7 +286,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.configs import get_config
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     if cfg.modality == "features":
         raise SystemExit("classifier serving lives in examples/serve_anytime.py")
     n_stages = len(cfg.stage_boundaries())
@@ -290,12 +304,15 @@ def main(argv=None):
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import decode_step, init_decode_cache, init_params
     from repro.training import checkpoint
 
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    enable_compile_cache()
+    params = init_params(cfg, jax.random.PRNGKey(args.seed))
     if args.ckpt:
         params, _ = checkpoint.load(args.ckpt, params)
+    params_gb = sum(x.nbytes for x in jax.tree.leaves(params)) / 1e9
     B = args.batch
     cache = init_decode_cache(cfg, B, slots=args.tokens + 1)
 
@@ -305,6 +322,17 @@ def main(argv=None):
 
     tok = (jnp.zeros((B, cfg.num_codebooks), jnp.int32)
            if cfg.modality == "audio_stub" else jnp.zeros((B,), jnp.int32))
+    # compile every depth before the clock starts (first call = compile +
+    # one step; the persistent cache turns a rerun's compile into a load)
+    t0 = time.perf_counter()
+    pos0 = jnp.zeros((B,), jnp.int32)
+    for step in steps:
+        jax.block_until_ready(step(params, cache, tok, pos0)[0].logits[-1])
+    compile_s = time.perf_counter() - t0
+    print(f"{cfg.name}: d_model={cfg.d_model} layers={cfg.num_layers} "
+          f"vocab={cfg.vocab_size} dtype={cfg.dtype} params={params_gb:.3f}GB "
+          f"first-call (compile) {compile_s:.2f}s on "
+          f"{jax.devices()[0].device_kind}")
     depth_hist = np.zeros(n_stages, np.int64)
 
     def advance(task, out):
@@ -321,16 +349,17 @@ def main(argv=None):
 
     svc = Service.from_spec(spec, steps=steps, params=params, cache=cache,
                             tok=tok, advance=advance)
-    t0 = time.time()
+    t0 = time.perf_counter()
     met = svc.run()
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     svc.close()
     ex = svc.executor
     if args.pipeline:
         print(f"pipelined decode: {ex.speculated - ex.spec_hits} speculative "
               f"deeper steps dispatched and discarded "
               f"({ex.spec_hits} consumed)")
-    print(f"\n{args.tokens} tokens in {dt:.1f}s; depth histogram "
+    print(f"\n{args.tokens} tokens in {dt:.3f}s "
+          f"({dt / max(1, args.tokens):.4f}s/token wall); depth histogram "
           f"{depth_hist.tolist()} (mean {met.mean_depth:.2f} "
           f"of {n_stages}) — stages shed: "
           f"{1 - depth_hist @ np.arange(1, n_stages+1) / (args.tokens * n_stages):.0%} compute saved")

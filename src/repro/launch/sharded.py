@@ -29,9 +29,10 @@ Registered as ``register_executor("device-sharded")`` from
 :mod:`repro.launch.serve` — *outside* the serving package, like the
 ``traffic`` source: the registry extension-point proof at executor scale.
 
-On a single-device host the mesh falls back to 1x1 and every result is
-bit-for-bit identical to ``device-batched`` (tests/test_sharded.py pins
-this parity), so CI exercises the full sharded path.
+On a 1x1 mesh every result is bit-for-bit identical to
+``device-batched`` (tests/test_sharded.py pins this parity), so
+single-device CI exercises the full sharded path.  A mesh larger than the
+host raises (:func:`repro.launch.mesh.make_serving_mesh`).
 """
 from __future__ import annotations
 
@@ -45,7 +46,7 @@ from repro.serving.runtime.device import DeviceExecutor
 #: executor_args keys understood by the ``device-sharded`` factory —
 #: the single source of truth ``ServeSpec._validate_sharded_args`` reads
 #: to reject anything else (typo guard)
-SHARDED_ARGS = ("dp", "tp", "mesh", "require", "collective")
+SHARDED_ARGS = ("dp", "tp", "mesh", "collective")
 
 
 def dp_buckets(buckets, dp: int) -> tuple:
@@ -118,20 +119,15 @@ class ShardedStageFns(BatchedStageFns):
 class ShardedDeviceExecutor(DeviceExecutor):
     """:class:`DeviceExecutor` over a mesh — same contract (async XLA
     dispatch, single in-flight batch, per-request hidden-state cache),
-    params committed once with the TP weight layout.
+    params committed once with the TP weight layout."""
 
-    ``fallback`` records that the requested ``(dp, tp)`` exceeded the
-    host's device count and the mesh degenerated to 1x1."""
-
-    def __init__(self, stage_fns, params, time_model, mesh, *,
-                 fallback: bool = False):
+    def __init__(self, stage_fns, params, time_model, mesh):
         params = jax.device_put(params,
                                 param_shardings(mesh, params, layout="tp"))
         super().__init__(stage_fns, params, time_model)
         self.mesh = mesh
         self.dp = int(mesh.shape[mesh.axis_names[0]])
         self.tp = int(mesh.shape[mesh.axis_names[1]])
-        self.fallback = fallback
 
 
 def build_sharded_executor(args: dict, ctx):
@@ -139,12 +135,11 @@ def build_sharded_executor(args: dict, ctx):
 
     ``args`` (all JSON-able; validated by ``ServeSpec.validate()``):
 
-    * ``dp`` / ``tp`` — data- / tensor-parallel ways (default 1 / 1).
+    * ``dp`` / ``tp`` — data- / tensor-parallel ways (default 1 / 1); a
+      host with fewer than ``dp * tp`` devices raises.
     * ``mesh`` — optional ``[dp_axis, tp_axis]`` axis names (default
       ``["data", "model"]``); a ready ``jax.sharding.Mesh`` may instead be
       passed as the ``mesh`` *resource*, skipping construction.
-    * ``require`` — raise instead of falling back to 1x1 when the host
-      lacks ``dp * tp`` devices (default False: CI-friendly fallback).
     * ``collective`` — seconds added to every dispatch's WCET when
       ``dp > 1`` (cross-replica sync pricing; default 0).
 
@@ -158,10 +153,8 @@ def build_sharded_executor(args: dict, ctx):
     mesh = ctx.resources.get("mesh")
     if mesh is None:
         axes = tuple(args.get("mesh") or ("data", "model"))
-        mesh = make_serving_mesh(dp, tp, axes=axes,
-                                 require=bool(args.get("require", False)))
+        mesh = make_serving_mesh(dp, tp, axes=axes)
     eff_dp = int(mesh.shape[mesh.axis_names[0]])
-    eff_tp = int(mesh.shape[mesh.axis_names[1]])
     params = ctx.resources["params"]
     stm = sharded_time_model(
         ctx.time_model, eff_dp, collective=float(args.get("collective", 0.0)))
@@ -181,7 +174,6 @@ def build_sharded_executor(args: dict, ctx):
     # everything downstream (StageBatcher, AdmissionController, deadline
     # adjustment, max_batch) prices the dp-wide global buckets
     ctx.time_model = stm
-    ex = ShardedDeviceExecutor(sfns, params, stm, mesh,
-                               fallback=eff_dp * eff_tp < dp * tp)
+    ex = ShardedDeviceExecutor(sfns, params, stm, mesh)
     ex.warmup = lambda sample_input: sfns.warmup(ex.params, sample_input)
     return ex

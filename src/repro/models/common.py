@@ -2,17 +2,12 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-# jax < 0.6 compat: shard_map graduated from jax.experimental
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                              # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,8 +110,17 @@ def apply_rope(x, positions, theta: float):
 # Initialization
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _scale_cast(x, scale, dtype):
+    return (scale * x).astype(dtype)
+
+
 def dense_init(key, shape, dtype, scale: float = 0.02):
-    return (scale * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+    # scale and cast in one program: the f32 draw is the only transient
+    # beside the result (eager ops would also hold its scaled f32 copy —
+    # 1.6 GB more for a full-width vocab matrix)
+    return _scale_cast(jax.random.normal(key, shape, jnp.float32),
+                       float(scale), jnp.dtype(dtype))
 
 
 def zeros_init(shape, dtype):

@@ -96,7 +96,7 @@ def exit_stats_unfused(h_rows, scale, w_out, *, eps: float = 1e-6,
 
 def exit_stats_fused(h_rows, scale, w_out, *, eps: float = 1e-6,
                      temperature: float = 1.0, block_rows: int = 8,
-                     block_v: int = 512, interpret: bool = True):
+                     block_v: int = 512, interpret: bool | None = None):
     """Fused exit epilogue: RMSNorm -> matmul -> online (max, lse, argmax)
     in one Pallas dispatch (repro.kernels.exit_confidence) — the V-sized
     logits row never leaves the kernel.  Same signature/returns as

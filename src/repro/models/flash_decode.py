@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.common import shard_map
 
 NEG_INF = -1e30
 
@@ -77,7 +76,7 @@ def flash_decode(q, k_cache, v_cache, slot_pos, cur_pos, *, window,
         acc_g = jax.lax.psum(acc * scale[..., None], seq)
         return (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q_.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(P(bspec, None, None, None),

@@ -213,6 +213,21 @@ def embed_one(cfg, params_embed, token, cur_pos):
 # params
 # ---------------------------------------------------------------------------
 
+def _stack_consuming(trees: list):
+    """``jnp.stack`` same-structured pytrees leaf by leaf, releasing each
+    source leaf once it is stacked (``trees`` is emptied): peak memory is
+    the inputs plus one stacked leaf, not two copies of a whole stage —
+    what keeps a full-width stage init inside one chip's HBM."""
+    treedef = jax.tree.structure(trees[0])
+    cols = list(zip(*(jax.tree.leaves(t) for t in trees)))
+    trees.clear()
+    cols.reverse()
+    out = []
+    while cols:
+        out.append(jnp.stack(cols.pop()))
+    return jax.tree.unflatten(treedef, out)
+
+
 def init_params(cfg, key):
     kg = KeyGen(key)
     layouts = stage_layouts(cfg)
@@ -225,7 +240,7 @@ def init_params(cfg, key):
             for _ in range(lay.n_scan):
                 periods.append(tuple(init_layer(cfg, s, kg())
                                      for s in lay.scan_sigs))
-            sp["scan"] = jax.tree.map(lambda *xs: jnp.stack(xs), *periods)
+            sp["scan"] = _stack_consuming(periods)
         sp["tail"] = [init_layer(cfg, layer_sig(cfg, i), kg())
                       for i in lay.tail]
         stages.append(sp)

@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import shard_residual, KeyGen, dense_init, param_dtype, rms_norm, shard, shard_map
+from repro.models.common import shard_residual, KeyGen, dense_init, param_dtype, rms_norm, shard
 from repro.models.ffn import ffn_core, init_ffn
 
 
@@ -217,7 +217,7 @@ def moe_alltoall(cfg, params, h2, ctx):
     down_spec = P(ep, fe_tp, None)                # we_down (E, fe, d)
     we_specs = {k_: (down_spec if k_ == "we_down" else gate_spec)
                 for k_ in we}
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp if dp else None, None), P(None, None), we_specs),
         out_specs=(P(dp if dp else None, None), P()),

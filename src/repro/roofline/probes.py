@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import set_mesh
 from repro.roofline.collectives import collective_bytes_from_hlo
 
 METRICS = ("flops", "bytes", "coll")
@@ -137,7 +136,7 @@ def _probe_layer(cfg, sig, ctx, mesh, *, batch, seq, mode, train,
     if train:
         base = fn
         fn = lambda *a: jax.value_and_grad(base)(*a)  # noqa: E731
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return _compile_cost(fn, args, shardings)
 
 
@@ -172,7 +171,7 @@ def _probe_slstm(cfg, ctx, mesh, *, batch, seq_probe, train):
         fwd = lambda *a: jax.value_and_grad(base)(*a)  # noqa: E731
     x = jax.ShapeDtypeStruct((batch, seq_probe, cfg.d_model), dt)
     h_sh = NamedSharding(mesh, P(ctx.dp, None, None))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return _compile_cost(fwd, (params, x), (p_sh, h_sh))
 
 
@@ -228,7 +227,7 @@ def probe_head(cfg, ctx, mesh, *, batch, seq, train):
         fn = fwd
         args = (params, inputs)
         shardings = (p_sh, in_sh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return _compile_cost(fn, args, shardings)
 
 

@@ -47,8 +47,8 @@ see ``docs/extending.md`` for the worked tutorial):
   arrival generators x per-class request mixes) and ``replay`` (recorded
   JSONL traces re-injected bit-for-bit);
 * ``repro.launch.serve`` — executors ``device-sharded`` (the batched
-  engine pjit-sharded over a ``(dp, tp)`` mesh, 1x1 fallback on
-  single-device hosts) and ``device-kernel`` (Pallas stage bodies: fused
+  engine pjit-sharded over a ``(dp, tp)`` mesh that the host must hold)
+  and ``device-kernel`` (Pallas stage bodies: fused
   exit-confidence epilogue, ragged decode batching over per-request KV
   caches, length-bucketed WCETs) plus the decode launcher's
   ``conf-target`` / ``decode`` / ``token-loop``.

@@ -22,9 +22,6 @@ def run_devices_script(body: str, timeout=420):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.mesh import make_debug_mesh
-        if not hasattr(jax, "set_mesh"):
-            # jax < 0.6 compat: Mesh is itself the context manager
-            jax.set_mesh = lambda m: m
         mesh = make_debug_mesh(2, 4)   # ('data' 2, 'model' 4)
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))

@@ -11,9 +11,9 @@ Four contracts, alongside tests/test_kernels.py's per-kernel sweeps:
   dispatch, logits never materialized) is bit-for-bit equal to the
   unfused reference on the anytime classifier (single vocab block).
 * **Ragged decode exactness** — co-batched decode through the kernel
-  route (per-row slot_pos) equals per-request singleton runs bitwise,
-  at ragged positions where the legacy jnp route (which shares row 0's
-  slot map) is not exact.
+  route (per-row slot_pos) equals per-request singleton runs (preds
+  exact, hidden states to float32 rounding) at ragged positions where
+  the legacy jnp route (which shares row 0's slot map) is not exact.
 * **Serving integration** — ``executor="device-kernel"`` matches
   ``device-batched`` predictions/depths end to end; length buckets
   gate batch formation; ``pipeline_depth >= 3`` stacks device windows;
@@ -304,54 +304,27 @@ def _decode_cfg():
 
 def test_ragged_decode_batch_bitwise_equals_singletons():
     """Co-batched decode at ragged positions through the Pallas route is
-    bitwise equal to running each request alone — the exactness the
-    per-row slot_pos map buys (the legacy jnp route shares row 0's)."""
-    from repro.launch.kernel import KernelDecodeStageFns
+    bitwise equal to a same-shape batch of each request alone — the
+    exactness the per-row slot_pos map buys (the legacy jnp route shares
+    row 0's).  Against the request run at batch 1, predictions are exact
+    and hidden states, confidences and cache rows agree to float32
+    rounding (1e-5 of the hidden scale), because XLA does not give
+    batch-shape-invariant bits."""
+    from repro.launch.kernel import KernelDecodeStageFns, ragged_decode_check
     from repro.launch.mesh import make_serving_mesh
-    from repro.models import (ParallelCtx, concat_decode_caches,
-                              init_decode_cache, init_params,
-                              slice_decode_cache)
+    from repro.models import ParallelCtx, init_params
     cfg = _decode_cfg()
     params = init_params(cfg, jax.random.PRNGKey(0))
     ctx = ParallelCtx(mesh=make_serving_mesh(1, 1), decode_attn="kernel")
     fns = KernelDecodeStageFns(cfg, (1, 2, 4), ctx)
-    rng = np.random.default_rng(0)
-    S = 16
     # three requests at ragged positions over a shared slot count
-    positions, states = [3, 9, 14], []
-    for i, pos in enumerate(positions):
-        cache = init_decode_cache(cfg, 1, S)
-        for p in range(pos):                       # warm to position pos
-            tok = jnp.array([int(rng.integers(cfg.vocab_size))], jnp.int32)
-            h = tok
-            for s in range(cfg.num_stages):
-                h, c, _pred, _conf = fns.fn(s)(
-                    params, h, cache[s], jnp.full((1,), p, jnp.int32))
-                cache[s] = c
-        tok = jnp.array([int(rng.integers(cfg.vocab_size))], jnp.int32)
-        states.append({"h": tok, "cache": cache,
-                       "cur_pos": jnp.full((1,), pos, jnp.int32)})
-    # batched pass
-    h_b = jnp.concatenate([st["h"] for st in states])
-    cur_b = jnp.concatenate([st["cur_pos"] for st in states])
-    outs_b = []
-    for s in range(cfg.num_stages):
-        cache_b = concat_decode_caches([st["cache"][s] for st in states])
-        h_b, cache_sb, pred_b, conf_b = fns.fn(s)(params, h_b, cache_b, cur_b)
-        outs_b.append((h_b, cache_sb, pred_b, conf_b))
-    # singleton passes must match bitwise
-    for i, st in enumerate(states):
-        h = st["h"]
-        for s in range(cfg.num_stages):
-            h, c, pred, conf = fns.fn(s)(params, h, st["cache"][s],
-                                         st["cur_pos"])
-            h_bs, cache_sb, pred_b, conf_b = outs_b[s]
-            assert np.array_equal(np.asarray(h), np.asarray(h_bs[i:i + 1]))
-            assert int(pred[0]) == int(pred_b[i])
-            assert float(conf[0]) == float(conf_b[i])
-            row = slice_decode_cache(cache_sb, i)
-            for a, b in zip(jax.tree.leaves(c), jax.tree.leaves(row)):
-                assert np.array_equal(np.asarray(a), np.asarray(b))
+    r = ragged_decode_check(fns, params, [3, 9, 14], 16, seed=0)
+    tol = 1e-5 * max(1.0, r["h_scale"])
+    assert r["same_shape_equal"]
+    assert r["pred_equal"]
+    assert r["h_err"] <= tol, r
+    assert r["cache_err"] <= tol, r
+    assert r["conf_err"] <= 1e-6, r
 
 
 def test_slice_concat_decode_cache_roundtrip():

@@ -92,3 +92,12 @@ def test_roofline_row_terms():
     assert row["hbm_frac"] == pytest.approx(14 / 16)
     assert row["fits"]
     assert row["useful_ratio"] > 0
+
+
+def test_peaks_table_keyed_by_device_kind():
+    from repro.roofline.peaks import peaks
+    v5e = peaks("TPU v5 lite")
+    assert v5e["flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    assert v5e["hbm_bytes"] == 16e9
+    with pytest.raises(ValueError, match="no published peak rates"):
+        peaks("cpu")

@@ -2,11 +2,11 @@
 
 The contract under test, alongside the tests/test_runtime.py goldens:
 
-* **Mesh fallback** — ``make_serving_mesh`` degenerates to 1x1 when the
-  host lacks ``dp * tp`` devices (``require=True`` raises instead), so one
-  ServeSpec runs everywhere and single-device CI exercises the full
-  sharded code path.
-* **Parity** — on the 1x1 fallback mesh, ``executor="device-sharded"``
+* **No mesh fallback** — ``make_serving_mesh`` raises when the host
+  lacks ``dp * tp`` devices, so a sharded result is never a silent
+  single-device one; single-device CI runs the full sharded code path on
+  an explicit 1x1 mesh.
+* **Parity** — on the 1x1 mesh, ``executor="device-sharded"``
   must reproduce ``device-batched`` results **bit-for-bit** under the
   virtual clock, for both a stream source and a traffic scenario.
 * **Pricing** — ``sharded_time_model`` scales buckets to dp-divisible
@@ -44,11 +44,16 @@ STAGE_TIMES = (0.002, 0.003, 0.004)
 # ---------------------------------------------------------------------------
 
 def test_make_serving_mesh_falls_back_to_1x1():
+    """There is no fallback any more: a mesh larger than the host
+    raises instead of degenerating to 1x1, and the 1x1 mesh is asked for
+    explicitly."""
     n = len(jax.devices())
-    mesh = make_serving_mesh(n + 1, 2)
-    assert dict(mesh.shape) == {"data": 1, "model": 1}
     with pytest.raises(ValueError, match="devices"):
-        make_serving_mesh(n + 1, 2, require=True)
+        make_serving_mesh(n + 1, 2)
+    with pytest.raises(ValueError, match="devices"):
+        spec = _stream_spec("device-sharded", {"dp": n + 1, "tp": 1})
+        Service.from_spec(spec, cfg=None, params={}).run([])
+    assert dict(make_serving_mesh(1, 1).shape) == {"data": 1, "model": 1}
     with pytest.raises(ValueError, match=">= 1"):
         make_serving_mesh(0, 1)
 
@@ -92,6 +97,7 @@ def test_sharded_time_model_prices_per_shard_bucket():
     {"dp": 0}, {"dp": -2}, {"dp": 2.5}, {"dp": True}, {"tp": 0},
     {"tp": "2"}, {"mesh": ["data"]}, {"mesh": ["x", "x"]},
     {"mesh": "data,model"}, {"collective": -1.0}, {"bogus": 1},
+    {"require": True},
 ])
 def test_validate_rejects_bad_sharded_args(bad):
     spec = ServeSpec(executor="device-sharded", executor_args=bad)
@@ -102,7 +108,7 @@ def test_validate_rejects_bad_sharded_args(bad):
 def test_validate_accepts_sharded_args():
     ServeSpec(executor="device-sharded",
               executor_args={"dp": 4, "tp": 2, "mesh": ["data", "model"],
-                             "require": False, "collective": 2e-4}).validate()
+                             "collective": 2e-4}).validate()
     ServeSpec(executor="device-sharded").validate()   # all defaults
 
 
@@ -146,14 +152,13 @@ def test_sharded_equals_batched_bitwise_stream(tiny_model):
                                 d_lo=0.2, d_hi=0.5, n_requests=12, seed=1)
     runs = {}
     for ex, ea in (("device-batched", {}),
-                   ("device-sharded", {"dp": 8, "tp": 8})):
+                   ("device-sharded", {"dp": 1, "tp": 1})):
         svc = Service.from_spec(_stream_spec(ex, ea), cfg=cfg, params=params)
         svc.run(list(stream))
         runs[ex] = svc
     sx = runs["device-sharded"].executor
-    if len(jax.devices()) == 1:          # the CI path: fallback engaged
-        assert sx.fallback and sx.dp == 1 and sx.tp == 1
-        assert sx.stage_fns.buckets == (1, 2, 4)
+    assert sx.dp == 1 and sx.tp == 1
+    assert sx.stage_fns.buckets == (1, 2, 4)
     assert _response_key(runs["device-sharded"].responses) \
         == _response_key(runs["device-batched"].responses)
 
@@ -172,7 +177,7 @@ def test_sharded_traffic_scenario_bitwise_parity(tiny_model):
     base.batching = {"buckets": [1, 2, 4], "stage_times": list(STAGE_TIMES),
                      "marginal": 0.25}
     recs = {}
-    for ex, ea in (("device-batched", {}), ("device-sharded", {"dp": 2})):
+    for ex, ea in (("device-batched", {}), ("device-sharded", {"dp": 1})):
         spec = dataclasses.replace(base, executor=ex, executor_args=ea)
         res = Service.from_spec(
             spec, cfg=cfg, params=params, n_samples=len(pool), labels=labels,
@@ -222,7 +227,7 @@ def test_sharded_hidden_state_cache_evicted_on_retire(tiny_model):
 
 @pytest.mark.slow
 def test_sharded_on_forced_two_device_mesh():
-    """dp=2 on two forced host devices: the mesh is NOT a fallback, global
+    """dp=2 on two forced host devices: a real two-device mesh, global
     buckets double, and results still match device-batched (row sharding
     keeps per-row math on a single device, so even bitwise holds)."""
     script = textwrap.dedent("""
@@ -258,7 +263,7 @@ def test_sharded_on_forced_two_device_mesh():
             svc.run(list(stream))
             runs[ex] = svc
         sx = runs["device-sharded"].executor
-        assert not sx.fallback and sx.dp == 2 and sx.tp == 1
+        assert sx.dp == 2 and sx.tp == 1
         assert sx.stage_fns.buckets == (2, 4, 8)
         assert sx.time_model.buckets == (2, 4, 8)
         key = lambda svc: [(r.sample, r.prediction, r.confidence, r.depth,
