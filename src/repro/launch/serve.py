@@ -47,6 +47,7 @@ import argparse
 import math
 import time
 
+from repro.serving.obs.hostspans import span
 from repro.serving.registry import (register_executor, register_policy,
                                     register_source)
 from repro.serving.service import ServeSpec, Service
@@ -128,19 +129,21 @@ class DecodeExecutor:
     def submit(self, stage, tasks, now):
         jnp = self._jnp
         task = tasks[0]
-        pos = jnp.full((self.tok.shape[0],), task.sample, jnp.int32)
-        if self._spec is not None and self._spec[:2] == (task.sample, stage):
-            out, new_cache = self._spec[2:]
-            self.spec_hits += 1
-        else:
-            out, new_cache = self.steps[stage](self.params, self.cache,
+        hit = self._spec is not None and self._spec[:2] == (task.sample, stage)
+        with span("repro.executor.launch", depth=stage + 1, hit=hit):
+            pos = jnp.full((self.tok.shape[0],), task.sample, jnp.int32)
+            if hit:
+                out, new_cache = self._spec[2:]
+                self.spec_hits += 1
+            else:
+                out, new_cache = self.steps[stage](self.params, self.cache,
+                                                   self.tok, pos)
+            self._spec = None
+            if self.speculate and stage + 1 < len(self.steps):
+                o2, c2 = self.steps[stage + 1](self.params, self.cache,
                                                self.tok, pos)
-        self._spec = None
-        if self.speculate and stage + 1 < len(self.steps):
-            o2, c2 = self.steps[stage + 1](self.params, self.cache, self.tok,
-                                           pos)
-            self._spec = (task.sample, stage + 1, o2, c2)
-            self.speculated += 1
+                self._spec = (task.sample, stage + 1, o2, c2)
+                self.speculated += 1
         self._running = (stage, tasks, out, new_cache, now)
 
     def finish_time(self):
@@ -149,14 +152,16 @@ class DecodeExecutor:
     def complete(self, clock):
         stage, tasks, out, new_cache, t0 = self._running
         self._running = None
-        self._jax.block_until_ready(out.logits[-1])
+        with span("repro.executor.wait"):
+            self._jax.block_until_ready(out.logits[-1])
         self.total_busy += clock.now() - t0
         self._done = (out, new_cache)
         return stage, tasks
 
     def commit(self, task, k):
         self.chosen = self._done
-        return float(self._jnp.mean(self._done[0].confidences[-1]))
+        with span("repro.executor.readback"):
+            return float(self._jnp.mean(self._done[0].confidences[-1]))
 
     def running_tasks(self):
         return list(self._running[1]) if self._running is not None else []
@@ -235,9 +240,10 @@ class TokenLoopSource:
                     sample=self._next)
 
     def on_retire(self, task, now):
-        out, new_cache = self.executor.chosen
-        self.executor.cache = new_cache
-        self.executor.tok = self.advance(task, out)
+        with span("repro.source.advance"):
+            out, new_cache = self.executor.chosen
+            self.executor.cache = new_cache
+            self.executor.tok = self.advance(task, out)
         self._next += 1
         if self._next < self.n_tokens:
             self._ready = True

@@ -22,6 +22,7 @@ import numpy as np
 from repro.models import stage_forward
 from repro.serving.batch.batcher import (DEFAULT_BUCKETS, BatchTimeModel,
                                          bucket_for)
+from repro.serving.obs.hostspans import span
 
 
 class StagingBuffers:
@@ -61,21 +62,23 @@ class StagingBuffers:
         n = len(pytrees)
         if not 0 < n <= bucket:
             raise ValueError(f"cannot pad {n} samples into bucket {bucket}")
-        leaves0, treedef = jax.tree.flatten(pytrees[0])
-        sig = tuple((tuple(lf.shape), np.dtype(lf.dtype)) for lf in leaves0)
-        key = (bucket, treedef, sig)
-        bufs = self._bufs.get(key)
-        if bufs is None:
-            bufs = [np.empty((bucket,) + tuple(lf.shape[1:]),
-                             dtype=np.dtype(lf.dtype)) for lf in leaves0]
-            self._bufs[key] = bufs
-        for i, tree in enumerate(pytrees):
-            leaves = leaves0 if i == 0 else treedef.flatten_up_to(tree)
-            for buf, leaf in zip(bufs, leaves):
-                buf[i] = np.asarray(leaf)[0]
-        for buf in bufs:                   # replicate last valid row
-            buf[n:] = buf[n - 1]
-        return treedef.unflatten(bufs), self.mask(bucket, n)
+        with span("repro.executor.stage_inputs", n=n, bucket=bucket):
+            leaves0, treedef = jax.tree.flatten(pytrees[0])
+            sig = tuple((tuple(lf.shape), np.dtype(lf.dtype))
+                        for lf in leaves0)
+            key = (bucket, treedef, sig)
+            bufs = self._bufs.get(key)
+            if bufs is None:
+                bufs = [np.empty((bucket,) + tuple(lf.shape[1:]),
+                                 dtype=np.dtype(lf.dtype)) for lf in leaves0]
+                self._bufs[key] = bufs
+            for i, tree in enumerate(pytrees):
+                leaves = leaves0 if i == 0 else treedef.flatten_up_to(tree)
+                for buf, leaf in zip(bufs, leaves):
+                    buf[i] = np.asarray(leaf)[0]
+            for buf in bufs:                   # replicate last valid row
+                buf[n:] = buf[n - 1]
+            return treedef.unflatten(bufs), self.mask(bucket, n)
 
 
 def pad_batch(pytrees, bucket: int, staging: StagingBuffers = None):

@@ -6,7 +6,10 @@ The observability layer for the serving stack: a passive
 device time splits), a scheduler decision audit log (which rule fired
 and the numbers behind it), and a :class:`MetricsRegistry`, exporting to
 JSONL and Chrome ``trace_event`` JSON.  Enable via ``ServeSpec(trace=
-{"enabled": True})``; see docs/observability.md.
+{"enabled": True})``; see docs/observability.md.  Beside it,
+:func:`span` marks the host's work as profiler annotations that share
+the device trace's clock (always on; recorded only while a profiler
+session is open).
 
 ```python
 import numpy as np
@@ -34,6 +37,7 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       LATENCY_BUCKETS, QUEUE_DEPTH_BUCKETS,
                       BATCH_OCCUPANCY_BUCKETS, DEPTH_BUCKETS)
 from .tracer import Span, RequestTrace, Tracer, TRACE_KEYS
+from .hostspans import span
 from .export import (write_jsonl, load_obs, chrome_trace,
                      validate_chrome_trace)
 
@@ -43,4 +47,5 @@ __all__ = [
     "DEPTH_BUCKETS",
     "Span", "RequestTrace", "Tracer", "TRACE_KEYS",
     "write_jsonl", "load_obs", "chrome_trace", "validate_chrome_trace",
+    "span",
 ]

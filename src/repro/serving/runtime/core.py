@@ -47,6 +47,7 @@ import numpy as np
 from repro.core.simulator import SimResult
 from repro.serving.batch.batcher import StageBatcher
 from repro.serving.batch.policy import as_batch_policy
+from repro.serving.obs.hostspans import span
 
 _EPS = 1e-12
 
@@ -198,10 +199,11 @@ class EngineCore:
         return any(t.executed < t.assigned_depth for t in self._active)
 
     def _retire(self, task, now: float, rejected: bool = False) -> None:
-        if task in self._active:
-            self._active.remove(task)
-        self.recorder.on_retire(task, now, rejected)
-        self.source.on_retire(task, now)
+        with span("repro.engine.retire"):
+            if task in self._active:
+                self._active.remove(task)
+            self.recorder.on_retire(task, now, rejected)
+            self.source.on_retire(task, now)
 
     def _expire(self, now: float) -> None:
         for t in list(self._active):
@@ -254,8 +256,10 @@ class EngineCore:
         cands = [t for t in self._active
                  if t.executed == stage and t.executed < t.assigned_depth
                  and t.deadline > now and id(t) not in inflight]
-        return stage, self._batcher.form(
-            leader, cands, now, rank=lambda t: self.policy.batch_rank(t, now))
+        with span("repro.scheduler"):
+            return stage, self._batcher.form(
+                leader, cands, now,
+                rank=lambda t: self.policy.batch_rank(t, now))
 
     def _preselect(self, now: float) -> None:
         """Pick the next batch while the device is busy — host work inside
@@ -263,7 +267,8 @@ class EngineCore:
         inflight = {id(t) for t in self.executor.running_tasks()}
         cands = [t for t in self._active if id(t) not in inflight]
         w0 = time.perf_counter()
-        nb = self.policy.next_batch(cands, now)
+        with span("repro.scheduler"):
+            nb = self.policy.next_batch(cands, now)
         self._account(self._cost(time.perf_counter() - w0))
         self._presel = None if nb is None or not nb[1] else (nb[0], nb[1])
 
@@ -290,7 +295,8 @@ class EngineCore:
             cands = [t for t in self._active if id(t) not in inflight] \
                 if inflight else self._active
             w0 = time.perf_counter()
-            nb = self.policy.next_batch(cands, now)
+            with span("repro.scheduler"):
+                nb = self.policy.next_batch(cands, now)
             self._account(self._cost(time.perf_counter() - w0))
         if nb is None or not nb[1]:
             return False
@@ -332,7 +338,8 @@ class EngineCore:
                 if self.tracer is not None:
                     self.tracer.on_stage_exit(t, now)
                 w0 = time.perf_counter()
-                self.policy.on_stage_done(self._active, t, now)
+                with span("repro.scheduler"):
+                    self.policy.on_stage_done(self._active, t, now)
                 self._account(self._cost(time.perf_counter() - w0))
         now = self.clock.now()
         for t in batch:
@@ -343,37 +350,45 @@ class EngineCore:
     def _admit(self, now: float) -> None:
         if self.source.next_time() > now + _EPS:
             return
-        task = self.source.pop(now)
-        if task is None:
-            return
-        tr = self.tracer
-        if tr is not None:
-            tr.on_admit(task, now, len(self._active))
-        if self.admission is not None:
-            dec = self.admission.apply(self._active, task, now)
-            if tr is not None:
-                tr.on_admission(task, now, dec)
-            if not dec.admitted:
-                # rejecting is a scheduling decision, not an accounting
-                # trick: the request counts as a miss and frees its client
-                self._retire(task, now, rejected=True)
+        with span("repro.engine.admit"):
+            task = self.source.pop(now)
+            if task is None:
                 return
-        elif tr is not None:
-            tr.on_admission(task, now, None)
-        self._active.append(task)
-        w0 = time.perf_counter()
-        self.policy.on_arrival(self._active, task, now)
-        self._account(self._cost(time.perf_counter() - w0))
-        if self.pipeline_depth >= 2 and self.executor.busy:
-            # refresh the pre-selection against the admission (and its
-            # replan) — more host work inside the still-open window
-            self._preselect(now)
+            tr = self.tracer
+            if tr is not None:
+                tr.on_admit(task, now, len(self._active))
+            if self.admission is not None:
+                dec = self.admission.apply(self._active, task, now)
+                if tr is not None:
+                    tr.on_admission(task, now, dec)
+                if not dec.admitted:
+                    # rejecting is a scheduling decision, not an accounting
+                    # trick: the request counts as a miss and frees its client
+                    self._retire(task, now, rejected=True)
+                    return
+            elif tr is not None:
+                tr.on_admission(task, now, None)
+            self._active.append(task)
+            w0 = time.perf_counter()
+            with span("repro.scheduler"):
+                self.policy.on_arrival(self._active, task, now)
+            self._account(self._cost(time.perf_counter() - w0))
+            if self.pipeline_depth >= 2 and self.executor.busy:
+                # refresh the pre-selection against the admission (and its
+                # replan) — more host work inside the still-open window
+                self._preselect(now)
 
     # ------------------------------------------------------------------
     def run(self):
+        # the anchor between the engine's clock and the profiler's: on a
+        # wall clock, engine time t lies at this span's start + t
+        with span("repro.engine.run"):
+            if self.clock.realtime:
+                self.clock.start()
+            return self._loop()
+
+    def _loop(self):
         clock, ex, src = self.clock, self.executor, self.source
-        if clock.realtime:
-            clock.start()
         while src.has_pending() or ex.busy or self._alive():
             now = clock.now()
             if self._pullins:

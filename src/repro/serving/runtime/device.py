@@ -43,6 +43,8 @@ import time
 import jax
 import numpy as np
 
+from repro.serving.obs.hostspans import span
+
 
 class SingleStageFns:
     """Adapt the unbatched engine's per-stage ``fn(params, h)`` list to the
@@ -130,7 +132,8 @@ class DeviceExecutor:
 
     def submit(self, stage: int, tasks: list, now: float) -> None:
         w0 = time.perf_counter()
-        payload = self._dispatch_stage(stage, tasks)
+        with span("repro.executor.launch", stage=stage, n=len(tasks)):
+            payload = self._dispatch_stage(stage, tasks)
         self.stage_host_time[stage] += time.perf_counter() - w0
         self._inflight.append((stage, tasks, payload, now))
 
@@ -142,10 +145,12 @@ class DeviceExecutor:
     def complete(self, clock):
         stage, tasks, payload, t0 = self._inflight.popleft()
         w0 = time.perf_counter()
-        self._block_on(payload)
+        with span("repro.executor.wait"):
+            self._block_on(payload)
         self.stage_device_time[stage] += time.perf_counter() - w0
         self.total_busy += clock.now() - t0
-        self._done = (stage, self._finalize(payload))
+        with span("repro.executor.readback"):
+            self._done = (stage, self._finalize(payload))
         return stage, tasks
 
     def commit(self, task, k: int) -> float:
