@@ -47,6 +47,8 @@ import argparse
 import math
 import time
 
+import numpy as np
+
 from repro.serving.obs.hostspans import span
 from repro.serving.registry import (register_executor, register_policy,
                                     register_source)
@@ -95,7 +97,10 @@ class DecodeExecutor:
     Depth *d*'s "stage" recomputes the token at depth d+1 from the current
     cache (exactly the bespoke loop this launcher used to hand-roll).
     With ``speculate`` the next-deeper step is dispatched asynchronously
-    before the current depth's confidence readback blocks.
+    before the current depth's confidence readback blocks.  Each step's
+    confidence starts its copy to the host as soon as the step is
+    dispatched, and ``commit`` averages it on the host: the readback
+    enqueues no device work, so it never waits behind a speculative step.
     """
 
     def __init__(self, steps, params, cache, tok, *, speculate=False):
@@ -113,6 +118,8 @@ class DecodeExecutor:
         self.total_busy = 0.0
         self.speculated = 0          # deeper steps dispatched speculatively
         self.spec_hits = 0           # ... that the schedule then consumed
+        self.readbacks = 0           # confidences read back in `commit`
+        self.readbacks_overlapped = 0  # ... with a deeper step in flight
         self._running = None
         self._spec = None            # (token, stage, out, new_cache)
         self._done = None
@@ -138,10 +145,12 @@ class DecodeExecutor:
             else:
                 out, new_cache = self.steps[stage](self.params, self.cache,
                                                    self.tok, pos)
+                out.confidences[-1].copy_to_host_async()
             self._spec = None
             if self.speculate and stage + 1 < len(self.steps):
                 o2, c2 = self.steps[stage + 1](self.params, self.cache,
                                                self.tok, pos)
+                o2.confidences[-1].copy_to_host_async()
                 self._spec = (task.sample, stage + 1, o2, c2)
                 self.speculated += 1
         self._running = (stage, tasks, out, new_cache, now)
@@ -160,8 +169,15 @@ class DecodeExecutor:
 
     def commit(self, task, k):
         self.chosen = self._done
-        with span("repro.executor.readback"):
-            return float(self._jnp.mean(self._done[0].confidences[-1]))
+        conf = self._done[0].confidences
+        overlapped = self._spec is not None
+        self.readbacks += 1
+        self.readbacks_overlapped += overlapped
+        # host-side mean of the copy `submit` started: nothing here
+        # dispatches to the device
+        with span("repro.executor.readback", depth=len(conf),
+                  overlapped=overlapped):
+            return float(np.asarray(conf[-1]).mean(dtype=np.float32))
 
     def running_tasks(self):
         return list(self._running[1]) if self._running is not None else []
@@ -308,7 +324,6 @@ def main(argv=None):
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from repro.launch.compile_cache import enable_compile_cache
     from repro.models import decode_step, init_decode_cache, init_params
@@ -363,7 +378,8 @@ def main(argv=None):
     if args.pipeline:
         print(f"pipelined decode: {ex.speculated - ex.spec_hits} speculative "
               f"deeper steps dispatched and discarded "
-              f"({ex.spec_hits} consumed)")
+              f"({ex.spec_hits} consumed); {ex.readbacks_overlapped} of "
+              f"{ex.readbacks} confidence readbacks overlapped a deeper step")
     print(f"\n{args.tokens} tokens in {dt:.3f}s "
           f"({dt / max(1, args.tokens):.4f}s/token wall); depth histogram "
           f"{depth_hist.tolist()} (mean {met.mean_depth:.2f} "

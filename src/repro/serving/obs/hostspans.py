@@ -25,7 +25,10 @@ Names are ``repro.<layer>.<what>``:
                                work
 ``repro.executor.stage_inputs``  ``StagingBuffers.stage`` (host batch rows)
 ``repro.executor.wait``        blocking on the device in ``complete``
-``repro.executor.readback``    device→host reads of a window's results
+``repro.executor.readback``    device→host reads of a window's results; the
+                               ``decode`` executor's reads only a copy
+                               ``submit`` started, dispatching nothing
+                               (metadata ``depth``, ``overlapped``)
 =============================  ==============================================
 """
 from __future__ import annotations

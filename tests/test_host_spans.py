@@ -83,7 +83,8 @@ def traced_decode(tmp_path_factory):
     with jax.profiler.trace(d):
         met = svc.run()
     svc.close()
-    return {"spans": host_spans(d), "obs": svc.obs, "met": met}
+    return {"spans": host_spans(d), "obs": svc.obs, "met": met,
+            "executor": svc.executor}
 
 
 def test_a_traced_decode_writes_the_program_spans(traced_decode):
@@ -100,6 +101,13 @@ def test_a_traced_decode_writes_the_program_spans(traced_decode):
         list(range(1, N_STAGES + 1)) * N_TOKENS
     # speculation dispatched depths 2 and 3 ahead of their turn
     assert [m["hit"] for *_x, m in launch] == [0, 1, 1] * N_TOKENS
+    # ... so depths 1 and 2 were read back with the next depth in flight
+    assert [m["depth"] for *_x, m in readback] == \
+        list(range(1, N_STAGES + 1)) * N_TOKENS
+    assert [m["overlapped"] for *_x, m in readback] == [1, 1, 0] * N_TOKENS
+    ex = traced_decode["executor"]
+    assert ex.readbacks == N_STAGES * N_TOKENS
+    assert ex.readbacks_overlapped == (N_STAGES - 1) * N_TOKENS
     assert names.count("repro.executor.wait") == N_STAGES * N_TOKENS
     assert names.count("repro.source.advance") == N_TOKENS
     assert names.count("repro.engine.retire") == N_TOKENS
