@@ -6,6 +6,14 @@ executors, policies and sources they resolve.  The configuration file's
 sizes are checked against the registered configuration before anything
 runs, so the benchmark never serves a model other than the one its file
 states.
+
+The check is a table: each configuration-file key (the published
+``config.json`` name) maps to an attribute of the program's
+``ModelConfig``, dotted where it is nested (``mla.kv_lora_rank``).  Which
+rows apply follows from the block: the GQA or the MLA sizes by the file's
+``attention`` (``"gqa"`` where absent), the expert sizes where the
+program's config has experts or the file states them.  A file extends
+the table with ``"program_fields": {file key: dotted attribute}``.
 """
 from __future__ import annotations
 
@@ -16,16 +24,52 @@ import sys
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "src")
 
-#: configuration-file key -> the program's ``ModelConfig`` field
+#: stands for "the file must state this key"
+REQUIRED = object()
+
+#: file key -> (the program's attribute, the value where the file is silent)
 FIELDS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "torch_dtype": "dtype", "qk_norm": "qk_norm",
-    "causal": "causal", "modality": "modality",
-    "mandatory_stages": "mandatory_stages",
+    "num_hidden_layers": ("num_layers", REQUIRED),
+    "hidden_size": ("d_model", REQUIRED),
+    "num_attention_heads": ("num_heads", REQUIRED),
+    "intermediate_size": ("d_ff", REQUIRED),
+    "vocab_size": ("vocab_size", REQUIRED),
+    "rope_theta": ("rope_theta", REQUIRED),
+    "rms_norm_eps": ("norm_eps", REQUIRED),
+    "torch_dtype": ("dtype", REQUIRED),
+    "mandatory_stages": ("mandatory_stages", REQUIRED),
+    "attention": ("attention", "gqa"),
+    "qk_norm": ("qk_norm", False),
+    "causal": ("causal", True),
+    "modality": ("modality", "text"),
+    "num_nextn_predict_layers": ("mtp", 0),
 }
+
+GQA_FIELDS = {
+    "num_key_value_heads": ("num_kv_heads", REQUIRED),
+    "head_dim": ("resolved_head_dim", REQUIRED),
+}
+
+MLA_FIELDS = {k: ("mla." + k, REQUIRED) for k in (
+    "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim")}
+
+MOE_FIELDS = {
+    "n_routed_experts": ("moe.num_experts", REQUIRED),
+    "num_experts_per_tok": ("moe.top_k", REQUIRED),
+    "moe_intermediate_size": ("moe.d_ff_expert", REQUIRED),
+    "n_shared_experts": ("moe.num_shared_experts", REQUIRED),
+    "first_k_dense_replace": ("moe.first_dense_layers", REQUIRED),
+    "moe_layer_freq": ("moe.moe_every", 1),
+}
+
+#: the keys a file may cut under ``reduced``: depth (with the MTP block
+#: and the leading dense layers) and the vocabulary held.  A width, a
+#: precision or a block kind listed there is refused.
+CUTS = ("num_hidden_layers", "first_k_dense_replace",
+        "num_nextn_predict_layers", "vocab_size")
+
+_MISSING = object()
 
 
 def import_program():
@@ -34,26 +78,77 @@ def import_program():
     import repro  # noqa: F401  (raises where the program is absent)
 
 
+def table(m: dict, cfg) -> dict:
+    """The rows of the check that apply to ``m`` and the program's ``cfg``."""
+    rows = dict(FIELDS)
+    if m.get("attention", "gqa") == "mla":
+        rows.update(MLA_FIELDS)
+        rows["num_key_value_heads"] = ("num_kv_heads", None)
+    else:
+        rows.update(GQA_FIELDS)
+    if cfg.moe is not None or any(k in m for k in MOE_FIELDS):
+        rows.update(MOE_FIELDS)
+    rows.update({k: (a, REQUIRED)
+                 for k, a in m.get("program_fields", {}).items()})
+    return rows
+
+
+def _get(obj, path: str):
+    for part in path.split("."):
+        obj = getattr(obj, part, _MISSING)
+        if obj is _MISSING or obj is None:
+            return _MISSING
+    return obj
+
+
+def _set(obj, path: str, value):
+    head, _, rest = path.partition(".")
+    if rest:
+        value = _set(getattr(obj, head), rest, value)
+    return dataclasses.replace(obj, **{head: value})
+
+
 def program_config(m: dict, overrides: dict | None = None):
-    """The registered ``ModelConfig`` named by ``m["registry"]`` (with
-    ``overrides``, the CPU tests' tiny sizes, replacing its fields),
-    checked key by key against ``m``: the program serves exactly the
-    sizes the file states, or nothing runs."""
+    """The registered ``ModelConfig`` named by ``m["registry"]``, checked
+    row by row against ``m``: the program serves exactly the sizes and
+    the block the file states, or nothing runs.
+
+    Before the check, the file's own value replaces the registered one
+    for each key of :data:`CUTS` that it lists under ``reduced``; where
+    the depth is cut, the file's ``stage_ends`` too.  Any other key of
+    the table listed there is refused.  ``overrides``
+    (the CPU tests' tiny sizes) replace fields of the registered config
+    first."""
     import_program()
     from repro.configs import get_config
     cfg = get_config(m["registry"])
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
+    rows = table(m, cfg)
     bad = []
-    for key, field in FIELDS.items():
-        if key in m and getattr(cfg, field) != m[key]:
-            bad.append((key, m[key], getattr(cfg, field)))
+    for key in [k for k in m.get("reduced", []) if k in rows]:
+        if key not in CUTS:
+            bad.append((key, m.get(key), "not a cut of depth or vocabulary"))
+            continue
+        try:
+            cfg = _set(cfg, rows[key][0], m[key])
+        except (AttributeError, KeyError, TypeError) as e:
+            bad.append((key, m.get(key), f"not applied: {e!r}"))
+    if "num_hidden_layers" in m.get("reduced", []):
+        cfg = dataclasses.replace(cfg, stage_ends=tuple(m["stage_ends"]))
+    for key, (attr, default) in rows.items():
+        want = m.get(key, default)
+        if want is REQUIRED:
+            bad.append((key, "not stated", _get(cfg, attr)))
+        elif want is not None and _get(cfg, attr) != want:
+            bad.append((key, want, _get(cfg, attr)))
     if tuple(cfg.stage_boundaries()) != tuple(m["stage_ends"]):
         bad.append(("stage_ends", m["stage_ends"], cfg.stage_boundaries()))
-    if cfg.ffn_type != "swiglu" or cfg.attention != "gqa" \
-            or set(cfg.period) != {"attn"} or cfg.moe is not None:
-        bad.append(("block", "dense swiglu gqa", cfg))
+    if cfg.ffn_type != "swiglu" or set(cfg.period) != {"attn"} \
+            or cfg.sliding_window is not None \
+            or (cfg.moe is not None and cfg.moe.moe_offset != 0):
+        bad.append(("block", "swiglu, full attention, experts from "
+                    "first_k_dense_replace on", cfg))
     if bad:
         raise RuntimeError(f"configuration file and program disagree: {bad}")
     return cfg
-

@@ -2,15 +2,30 @@
 
 The benchmark makes the weights itself, so that the reference takes
 nothing the program made.  They come out in the program's parameter
-layout: ``embed``, per-stage layer groups (a stacked ``scan`` group for a
-stage of two or more layers, else a ``prefix`` list), per-stage exit norm
-scales, and the shared vocabulary projection.  :func:`check_layout`
-compares that layout with what the program's own ``init_params`` would
-build, so a change of layout fails at set-up, not as a wrong answer.
+layout, read from the configuration file's block keys: ``embed``, per
+stage the layer groups as the program groups them (leading dense layers
+in ``prefix``, a stacked ``scan`` group over two or more periods, then
+``tail``; a stage with fewer periods is a ``prefix`` list), per-stage
+exit norm scales, the shared vocabulary projection, and with
+``num_nextn_predict_layers`` the multi-token-prediction block.
+:func:`check_layout` compares that layout with what the program's own
+``init_params`` would build, so a change of layout fails at set-up, not
+as a wrong answer.
 
-Scales: projections N(0, 0.02), output projections ``wo`` and ``w_down``
-N(0, 0.02 / sqrt(layers)), norm scales N(0, 0.1) (they act as
-``1 + scale``).
+Leaves per mixer: GQA (``wq``, ``wk``, ``wv``, ``wo``, with ``qk_norm``
+``q_norm`` and ``k_norm``) or MLA (``wq_a``, ``q_norm``, ``wq_b``,
+``wkv_a``, ``kv_norm``, ``wkv_b``, ``wo``), each with its norm ``ln``.
+Per feed-forward: dense SwiGLU (``w_gate``, ``w_up``, ``w_down``) or
+experts (a float32 ``router`` over the ``n_routed_experts``, their
+``we_gate``, ``we_up``, ``we_down``, and the shared experts' SwiGLU under
+``shared``).
+
+Scales by leaf name: norm scales N(0, 0.1) (they act as ``1 + scale``),
+output projections ``wo``, ``w_down`` and ``we_down`` N(0, 0.02 /
+sqrt(layers)), the rest N(0, 0.02).  Each leaf draws from the seed's key
+folded with its own tag (``s{stage}/{part}/{name}`` for a scan group,
+``l{layer}/...`` for a single layer), so a leaf's values do not depend on
+which other leaves exist.
 """
 from __future__ import annotations
 
@@ -20,28 +35,82 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+NORMS = ("ln", "q_norm", "k_norm", "kv_norm")
+OUT_PROJ = ("wo", "w_down", "we_down")
+
 
 def stage_spans(m: dict):
     ends = list(m["stage_ends"])
     return list(zip([0] + ends[:-1], ends))
 
 
-def layer_shapes(m: dict) -> dict:
-    d, H, KV, hd = (m["hidden_size"], m["num_attention_heads"],
-                    m["num_key_value_heads"], m["head_dim"])
-    f = m["intermediate_size"]
+def is_moe_layer(m: dict, i: int) -> bool:
+    """Layer ``i`` has routed experts: from ``first_k_dense_replace`` on,
+    every ``moe_layer_freq``-th layer."""
+    return "n_routed_experts" in m and i >= m["first_k_dense_replace"] \
+        and i % m.get("moe_layer_freq", 1) == 0
+
+
+def mixer_shapes(m: dict) -> dict:
+    d, H = m["hidden_size"], m["num_attention_heads"]
+    if m.get("attention", "gqa") == "mla":
+        q, kv = m["q_lora_rank"], m["kv_lora_rank"]
+        nope, rope, v = (m["qk_nope_head_dim"], m["qk_rope_head_dim"],
+                         m["v_head_dim"])
+        return {"ln": (d,), "wq_a": (d, q), "q_norm": (q,),
+                "wq_b": (q, H * (nope + rope)), "wkv_a": (d, kv + rope),
+                "kv_norm": (kv,), "wkv_b": (kv, H * (nope + v)),
+                "wo": (H * v, d)}
+    KV, hd = m["num_key_value_heads"], m["head_dim"]
     mixer = {"ln": (d,), "wq": (d, H * hd), "wk": (d, KV * hd),
              "wv": (d, KV * hd), "wo": (H * hd, d)}
     if m["qk_norm"]:
         mixer.update(q_norm=(hd,), k_norm=(hd,))
-    ffn = {"ln": (d,), "w_up": (d, f), "w_down": (f, d), "w_gate": (d, f)}
-    return {"mixer": mixer, "ffn": ffn}
+    return mixer
+
+
+def ffn_shapes(m: dict, moe: bool) -> dict:
+    d = m["hidden_size"]
+    if not moe:
+        f = m["intermediate_size"]
+        return {"ln": (d,), "w_up": (d, f), "w_down": (f, d),
+                "w_gate": (d, f)}
+    fe, E = m["moe_intermediate_size"], m["n_routed_experts"]
+    p = {"ln": (d,), "router": (d, E), "we_gate": (E, d, fe),
+         "we_up": (E, d, fe), "we_down": (E, fe, d)}
+    fs = fe * m["n_shared_experts"]
+    if fs:
+        p["shared"] = {"w_up": (d, fs), "w_down": (fs, d), "w_gate": (d, fs)}
+    return p
+
+
+def layer_shapes(m: dict, moe: bool = False) -> dict:
+    return {"mixer": mixer_shapes(m), "ffn": ffn_shapes(m, moe)}
+
+
+def stage_groups(m: dict):
+    """Per stage ``(prefix, scan, tail)`` as the program's
+    ``stage_layouts`` groups them: ``scan`` is ``None`` or ``(start,
+    periods, period length)``; ``prefix`` and ``tail`` are layer indices."""
+    moe = "n_routed_experts" in m
+    fd = m["first_k_dense_replace"] if moe else 0
+    E = m.get("moe_layer_freq", 1) if moe else 1
+    out = []
+    for a, b in stage_spans(m):
+        g0 = max(a, fd)
+        n = max(0, (b - g0) // E)
+        if n < 2:
+            out.append((list(range(a, b)), None, []))
+        else:
+            out.append((list(range(a, g0)), (g0, n, E),
+                        list(range(g0 + n * E, b))))
+    return out
 
 
 def _scale(name: str, m: dict) -> float:
-    if name in ("ln", "q_norm", "k_norm"):
+    if name in NORMS:
         return 0.1
-    if name in ("wo", "w_down"):
+    if name in OUT_PROJ:
         return 0.02 / float(m["num_hidden_layers"]) ** 0.5
     return 0.02
 
@@ -51,29 +120,48 @@ def _draw(key, tag: str, shape, dtype, scale):
     return jax.random.normal(k, shape, dtype) * jnp.asarray(scale, dtype)
 
 
+def _leaves(key, m, dt, tag, shapes, lead=()):
+    out = {}
+    for name, shp in shapes.items():
+        t = f"{tag}/{name}"
+        if isinstance(shp, dict):
+            out[name] = _leaves(key, m, dt, t, shp, lead)
+        else:
+            ldt = jnp.float32 if name == "router" else dt
+            out[name] = _draw(key, t, lead + shp, ldt, _scale(name, m))
+    return out
+
+
 def _build(key, m: dict):
     dt = jnp.dtype(m["torch_dtype"])
     d, V = m["hidden_size"], m["vocab_size"]
-    shapes = layer_shapes(m)
+
+    def layer(i, tag=None, n=0):
+        lead = (n,) if n else ()
+        return _leaves(key, m, dt, tag or f"l{i}",
+                       layer_shapes(m, is_moe_layer(m, i)), lead)
+
     stages = []
-    for s, (a, b) in enumerate(stage_spans(m)):
-        def group(n, tag):
-            lead = (n,) if n else ()
-            return {part: {name: _draw(key, f"{tag}/{part}/{name}",
-                                       lead + shp, dt, _scale(name, m))
-                           for name, shp in leaves.items()}
-                    for part, leaves in shapes.items()}
-        if b - a >= 2:
-            stages.append({"prefix": [], "scan": (group(b - a, f"s{s}"),),
-                           "tail": []})
-        else:
-            stages.append({"prefix": [group(0, f"l{i}") for i in range(a, b)],
-                           "tail": []})
-    return {"embed": {"tok": _draw(key, "embed/tok", (V, d), dt, 0.02)}, "stages": stages,
-            "exits": [{"ln": _draw(key, f"exit{s}/ln", (d,), dt, 0.1)}
-                      for s in range(len(stages))],
-            "exit_shared": {"w_out": _draw(key, "exit/w_out", (d, V), dt,
-                                           0.02)}}
+    for s, (prefix, scan, tail) in enumerate(stage_groups(m)):
+        st = {"prefix": [layer(i) for i in prefix]}
+        if scan:
+            g0, n, E = scan
+            st["scan"] = tuple(layer(g0 + j, f"s{s}" + (f".{j}" if j else ""),
+                                     n) for j in range(E))
+        st["tail"] = [layer(i) for i in tail]
+        stages.append(st)
+    params = {"embed": {"tok": _draw(key, "embed/tok", (V, d), dt, 0.02)},
+              "stages": stages,
+              "exits": [{"ln": _draw(key, f"exit{s}/ln", (d,), dt, 0.1)}
+                        for s in range(len(stages))],
+              "exit_shared": {"w_out": _draw(key, "exit/w_out", (d, V), dt,
+                                             0.02)}}
+    if m.get("num_nextn_predict_layers", 0):
+        params["mtp"] = {
+            "proj": _draw(key, "mtp/proj", (2 * d, d), dt, 0.02),
+            "block": _leaves(key, m, dt, "mtp/block", layer_shapes(m)),
+            "exit": {"ln": _draw(key, "mtp/exit/ln", (d,), dt, 0.1)}}
+    return params
 
 
 def seed_key(seed: int):

@@ -7,7 +7,9 @@ CPU the program reads a ``conf_rel_err`` of 0.0011-0.0024 and a decode
 ``logit_gap`` of 0-0.0023 over seeds 1-3, the float8 control 0.019-0.027
 and 0.011-0.016.
 The harness runs as on the chip, except that it is handed the CPU devices
-and the kernels run in interpret mode.
+and the kernels run in interpret mode.  :data:`MLA_MOE` is a second block,
+latent attention and routed experts, for the harness's block and layout
+checks and the decode cell; it has no cell and no reference here.
 """
 from __future__ import annotations
 
@@ -59,6 +61,49 @@ DECODE = {
     "policy": {"name": "conf-target", "args": {"target": 0.7}},
     "correct": {"limits": {"logit_gap": 0.006}},
 }
+
+#: a tiny latent-attention + routed-expert block: the registered
+#: DeepSeek-V3 at CPU widths, its file keyed as the published config.json
+#: is.  Depth and vocabulary are cut through ``reduced``, as a chip
+#: configuration would cut them; the widths are the overrides below.
+MLA_MOE = {
+    "name": "tiny-mla-moe", "registry": "deepseek-v3-671b",
+    "attention": "mla", "hidden_size": 64, "intermediate_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 4,
+    "num_hidden_layers": 5, "q_lora_rank": 32, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "n_routed_experts": 8, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 32, "n_shared_experts": 1,
+    "first_k_dense_replace": 1, "moe_layer_freq": 1,
+    "num_nextn_predict_layers": 1, "rms_norm_eps": 1e-6,
+    "rope_theta": 10000, "torch_dtype": "bfloat16", "vocab_size": 512,
+    "causal": True, "modality": "text", "stage_ends": [3, 4, 5],
+    "mandatory_stages": 1, "reduced": ["num_hidden_layers", "vocab_size"],
+}
+
+
+def mla_moe_overrides() -> dict:
+    """The tiny widths of :data:`MLA_MOE` as fields of the registered
+    config (the depth, the vocabulary and the stage ends come from the
+    file's ``reduced``)."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.configs.base import MLAConfig
+    m = MLA_MOE
+    moe = get_config(m["registry"]).moe
+    return dict(
+        name="tiny-mla-moe", d_model=m["hidden_size"],
+        num_heads=m["num_attention_heads"],
+        num_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
+        mla=MLAConfig(**{k: m[k] for k in (
+            "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim")}),
+        moe=dataclasses.replace(
+            moe, num_experts=m["n_routed_experts"],
+            top_k=m["num_experts_per_tok"],
+            d_ff_expert=m["moe_intermediate_size"],
+            first_dense_layers=m["first_k_dense_replace"]))
+
 
 CELLS = {
     "tiny.prefill": ("tiny-text", "tiny-prefill", PREFILL),
