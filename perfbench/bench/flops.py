@@ -12,12 +12,15 @@ the latent at every position, its decode takes the absorbed form (the
 least work that computes the same equations).  The feed-forward is a
 dense SwiGLU of ``intermediate_size`` or, from ``first_k_dense_replace``
 on, an expert layer: the router over all routed experts, the shared
-experts, and the SwiGLU of each token's ``num_experts_per_tok`` routed
-experts.
+experts, and the SwiGLU of the routed experts this chip holds.  Of a
+token's ``num_experts_per_tok`` routed experts a chip that holds ``held``
+of the ``n_routed_experts`` computes ``num_experts_per_tok * held /
+n_routed_experts``, the expected share under uniform routing; where the
+file states no ``ep_size`` it holds them all.
 """
 from __future__ import annotations
 
-from bench.weights import is_moe_layer
+from bench.weights import held_experts, is_moe_layer
 
 
 def itemsize(m: dict) -> int:
@@ -50,12 +53,39 @@ def router_flops(m: dict) -> float:
     return 2.0 * m["hidden_size"] * m["n_routed_experts"]
 
 
+def routed_per_token(m: dict) -> float:
+    """The routed experts of one token that this chip computes."""
+    return m["num_experts_per_tok"] * held_experts(m) / m["n_routed_experts"]
+
+
 def expert_layer_flops(m: dict) -> float:
     """An expert layer's feed-forward per token: the router, the shared
-    experts, and the routed experts' SwiGLU."""
+    experts, and the held routed experts' SwiGLU."""
     fe = m["moe_intermediate_size"]
     return router_flops(m) + swiglu_flops(m, fe * m["n_shared_experts"]) \
-        + m["num_experts_per_tok"] * swiglu_flops(m, fe)
+        + routed_per_token(m) * swiglu_flops(m, fe)
+
+
+def experts_touched(m: dict, rows: int) -> float:
+    """The expected number of held experts that ``rows`` tokens route to,
+    each token to ``num_experts_per_tok`` distinct experts of the
+    ``n_routed_experts`` at uniform: ``held * (1 - (1 - k/E) ** rows)``."""
+    k, E = m["num_experts_per_tok"], m["n_routed_experts"]
+    return held_experts(m) * (1.0 - (1.0 - k / E) ** rows)
+
+
+def expert_layer_bytes(m: dict, rows: int,
+                       touched: float | None = None) -> float:
+    """Weight bytes one expert layer's feed-forward reads in a step of
+    ``rows`` tokens: the float32 router (with its ``router_bias``), the
+    shared experts, and the SwiGLU of each held expert the step touches:
+    ``touched`` where a reader observed it, else :func:`experts_touched`."""
+    d, E = m["hidden_size"], m["n_routed_experts"]
+    if touched is None:
+        touched = experts_touched(m, rows)
+    router = d * E + (E if m.get("topk_method") == "noaux_tc" else 0)
+    swiglu = 3 * d * m["moe_intermediate_size"] * itemsize(m)
+    return 4 * router + (m["n_shared_experts"] + touched) * swiglu
 
 
 def ffn_flops(m: dict, layer: int = 0) -> float:

@@ -14,12 +14,21 @@ rows apply follows from the block: the GQA or the MLA sizes by the file's
 ``attention`` (``"gqa"`` where absent), the expert sizes where the
 program's config has experts or the file states them.  A file extends
 the table with ``"program_fields": {file key: dotted attribute}``.
+
+Rows whose value where the file is silent is ``None`` are checked only
+where a file states them: the experts a chip holds (``ep_size``, each of
+that many chips holding ``n_routed_experts / ep_size``) and the router's
+keys (``scoring_func``, ``topk_method``, ``n_group``, ``topk_group``,
+``routed_scaling_factor``, ``norm_topk_prob``).  A file that states one
+is refused while the program has no such attribute.
 """
 from __future__ import annotations
 
 import dataclasses
 import os
 import sys
+
+from bench.weights import held_experts
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "src")
@@ -61,13 +70,18 @@ MOE_FIELDS = {
     "n_shared_experts": ("moe.num_shared_experts", REQUIRED),
     "first_k_dense_replace": ("moe.first_dense_layers", REQUIRED),
     "moe_layer_freq": ("moe.moe_every", 1),
+    "ep_size": ("moe.ep_size", None),
 }
+MOE_FIELDS.update({k: ("moe." + k, None) for k in (
+    "scoring_func", "topk_method", "n_group", "topk_group",
+    "routed_scaling_factor", "norm_topk_prob")})
 
 #: the keys a file may cut under ``reduced``: depth (with the MTP block
-#: and the leading dense layers) and the vocabulary held.  A width, a
-#: precision or a block kind listed there is refused.
+#: and the leading dense layers), the vocabulary and the routed experts
+#: held (``ep_size``).  A width, a precision or a block kind listed there
+#: is refused.
 CUTS = ("num_hidden_layers", "first_k_dense_replace",
-        "num_nextn_predict_layers", "vocab_size")
+        "num_nextn_predict_layers", "vocab_size", "ep_size")
 
 _MISSING = object()
 
@@ -101,6 +115,14 @@ def _get(obj, path: str):
     return obj
 
 
+def _has(obj, path: str) -> bool:
+    for part in path.split("."):
+        if not hasattr(obj, part):
+            return False
+        obj = getattr(obj, part)
+    return True
+
+
 def _set(obj, path: str, value):
     head, _, rest = path.partition(".")
     if rest:
@@ -128,19 +150,33 @@ def program_config(m: dict, overrides: dict | None = None):
     bad = []
     for key in [k for k in m.get("reduced", []) if k in rows]:
         if key not in CUTS:
-            bad.append((key, m.get(key), "not a cut of depth or vocabulary"))
+            bad.append((key, m.get(key), "not a cut of depth, vocabulary "
+                        "or experts held"))
             continue
+        if not _has(cfg, rows[key][0]):
+            continue            # refused below: the program has no such field
         try:
             cfg = _set(cfg, rows[key][0], m[key])
         except (AttributeError, KeyError, TypeError) as e:
             bad.append((key, m.get(key), f"not applied: {e!r}"))
     if "num_hidden_layers" in m.get("reduced", []):
         cfg = dataclasses.replace(cfg, stage_ends=tuple(m["stage_ends"]))
+    if "ep_size" in m and "n_routed_experts" in m:
+        try:
+            held_experts(m)
+        except ValueError as e:
+            bad.append(("ep_size", m["ep_size"], str(e)))
     for key, (attr, default) in rows.items():
         want = m.get(key, default)
         if want is REQUIRED:
             bad.append((key, "not stated", _get(cfg, attr)))
-        elif want is not None and _get(cfg, attr) != want:
+        elif want is None:
+            if key in m and default is REQUIRED:
+                bad.append((key, None, "stated null; the harness builds "
+                            "this part only at a stated size"))
+        elif not _has(cfg, attr):
+            bad.append((key, want, f"the program has no {attr}"))
+        elif _get(cfg, attr) != want:
             bad.append((key, want, _get(cfg, attr)))
     if tuple(cfg.stage_boundaries()) != tuple(m["stage_ends"]):
         bad.append(("stage_ends", m["stage_ends"], cfg.stage_boundaries()))

@@ -16,16 +16,21 @@ Leaves per mixer: GQA (``wq``, ``wk``, ``wv``, ``wo``, with ``qk_norm``
 ``q_norm`` and ``k_norm``) or MLA (``wq_a``, ``q_norm``, ``wq_b``,
 ``wkv_a``, ``kv_norm``, ``wkv_b``, ``wo``), each with its norm ``ln``.
 Per feed-forward: dense SwiGLU (``w_gate``, ``w_up``, ``w_down``) or
-experts (a float32 ``router`` over the ``n_routed_experts``, their
-``we_gate``, ``we_up``, ``we_down``, and the shared experts' SwiGLU under
-``shared``).
+experts (a float32 ``router`` over the ``n_routed_experts``, with
+``topk_method`` ``"noaux_tc"`` its float32 selection bias
+``router_bias``, one per routed expert; the ``we_gate``, ``we_up``,
+``we_down`` of the experts this chip holds, :func:`held_experts`; and the
+shared experts' SwiGLU under ``shared``).
 
 Scales by leaf name: norm scales N(0, 0.1) (they act as ``1 + scale``),
 output projections ``wo``, ``w_down`` and ``we_down`` N(0, 0.02 /
-sqrt(layers)), the rest N(0, 0.02).  Each leaf draws from the seed's key
-folded with its own tag (``s{stage}/{part}/{name}`` for a scan group,
-``l{layer}/...`` for a single layer), so a leaf's values do not depend on
-which other leaves exist.
+sqrt(layers)), ``router_bias`` N(0, 0.005), the rest N(0, 0.02).  At
+that scale the bias changes the top-8 set of about half the rows at
+DeepSeek-V3's router widths (7168 x 256, rows of unit variance), so a
+program that ignores it computes other experts.  Each leaf draws from
+the seed's key folded with its own tag (``s{stage}/{part}/{name}`` for a
+scan group, ``l{layer}/...`` for a single layer), so a leaf's values do
+not depend on which other leaves exist.
 """
 from __future__ import annotations
 
@@ -37,6 +42,11 @@ import numpy as np
 
 NORMS = ("ln", "q_norm", "k_norm", "kv_norm")
 OUT_PROJ = ("wo", "w_down", "we_down")
+FLOAT32 = ("router", "router_bias")
+BIAS_SCALE = 0.005
+
+#: the fewest routed experts a chip may hold (model-configs guide, section 4)
+MIN_HELD = 8
 
 
 def stage_spans(m: dict):
@@ -51,10 +61,27 @@ def is_moe_layer(m: dict, i: int) -> bool:
         and i % m.get("moe_layer_freq", 1) == 0
 
 
+def held_experts(m: dict) -> int:
+    """The routed experts one chip holds, experts ``0 .. held - 1`` (rank
+    0): ``n_routed_experts / ep_size``, all of them where ``ep_size`` is
+    not stated.  Raises unless ``ep_size`` divides ``n_routed_experts``
+    and, where it cuts, leaves :data:`MIN_HELD` or more."""
+    E, ep = m["n_routed_experts"], m.get("ep_size", 1)
+    if E % ep:
+        raise ValueError(f"ep_size {ep} does not divide n_routed_experts {E}")
+    if ep > 1 and E // ep < MIN_HELD:
+        raise ValueError(f"ep_size {ep} leaves {E // ep} of {E} experts "
+                         f"held, under {MIN_HELD}")
+    return E // ep
+
+
 def mixer_shapes(m: dict) -> dict:
     d, H = m["hidden_size"], m["num_attention_heads"]
     if m.get("attention", "gqa") == "mla":
         q, kv = m["q_lora_rank"], m["kv_lora_rank"]
+        if q is None:
+            raise ValueError("q_lora_rank null: the direct query "
+                             "projection is not built")
         nope, rope, v = (m["qk_nope_head_dim"], m["qk_rope_head_dim"],
                          m["v_head_dim"])
         return {"ln": (d,), "wq_a": (d, q), "q_norm": (q,),
@@ -76,8 +103,11 @@ def ffn_shapes(m: dict, moe: bool) -> dict:
         return {"ln": (d,), "w_up": (d, f), "w_down": (f, d),
                 "w_gate": (d, f)}
     fe, E = m["moe_intermediate_size"], m["n_routed_experts"]
-    p = {"ln": (d,), "router": (d, E), "we_gate": (E, d, fe),
-         "we_up": (E, d, fe), "we_down": (E, fe, d)}
+    h = held_experts(m)
+    p = {"ln": (d,), "router": (d, E), "we_gate": (h, d, fe),
+         "we_up": (h, d, fe), "we_down": (h, fe, d)}
+    if m.get("topk_method") == "noaux_tc":
+        p["router_bias"] = (E,)
     fs = fe * m["n_shared_experts"]
     if fs:
         p["shared"] = {"w_up": (d, fs), "w_down": (fs, d), "w_gate": (d, fs)}
@@ -112,6 +142,8 @@ def _scale(name: str, m: dict) -> float:
         return 0.1
     if name in OUT_PROJ:
         return 0.02 / float(m["num_hidden_layers"]) ** 0.5
+    if name == "router_bias":
+        return BIAS_SCALE
     return 0.02
 
 
@@ -127,7 +159,7 @@ def _leaves(key, m, dt, tag, shapes, lead=()):
         if isinstance(shp, dict):
             out[name] = _leaves(key, m, dt, t, shp, lead)
         else:
-            ldt = jnp.float32 if name == "router" else dt
+            ldt = jnp.float32 if name in FLOAT32 else dt
             out[name] = _draw(key, t, lead + shp, ldt, _scale(name, m))
     return out
 
