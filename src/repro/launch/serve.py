@@ -12,7 +12,8 @@ the registry's extension points (no core module touched):
 
 * policy ``conf-target`` — assign full depth, stop deepening the moment
   the measured exit confidence reaches the target;
-* executor ``decode`` — jitted per-depth decode steps; with
+* executor ``decode`` — jitted per-depth decode steps, each depth after
+  the first resuming from the one before; with
   ``speculate=True`` (``--pipeline``) the next-deeper step is dispatched
   (XLA async) before the current depth's confidence readback, so the
   host's read-and-decide overlaps device compute — a speculatively
@@ -44,6 +45,7 @@ d_model 2560, bf16 — about 8.8 GB of params, one TPU v5e chip);
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import math
 import time
 
@@ -94,12 +96,16 @@ def _make_conf_target(args, ctx):
 class DecodeExecutor:
     """Jitted per-depth decode steps behind the runtime Executor contract.
 
-    Depth *d*'s "stage" recomputes the token at depth d+1 from the current
-    cache (exactly the bespoke loop this launcher used to hand-roll).
-    With ``speculate`` the next-deeper step is dispatched asynchronously
-    before the current depth's confidence readback blocks.  Each step's
-    confidence starts its copy to the host as soon as the step is
-    dispatched, and ``commit`` averages it on the host: the readback
+    Depth *d*'s "stage" takes the token to depth d+1.  Depth 1 runs from
+    the embedding and the committed cache; depth d+1 resumes from depth
+    d: its step gets depth d's ``h_final`` as a
+    :class:`~repro.models.DecodeFrom` and depth d's new cache, and runs
+    only stage d+1 and its exit.  The other stages' exits and caches are
+    carried over from depth d on the host, so each output still holds one
+    exit per stage run and a whole cache.  With ``speculate`` the next-deeper step is dispatched
+    asynchronously before the current depth's confidence readback blocks.
+    Each step's confidence starts its copy to the host as soon as the step
+    is dispatched, and ``commit`` averages it on the host: the readback
     enqueues no device work, so it never waits behind a speculative step.
     """
 
@@ -109,7 +115,9 @@ class DecodeExecutor:
         # re-importing in the per-token hot methods
         import jax
         import jax.numpy as jnp
-        self._jax, self._jnp = jax, jnp
+
+        from repro.models import DecodeFrom
+        self._jax, self._jnp, self._from = jax, jnp, DecodeFrom
         self.steps = steps
         self.params = params
         self.cache = cache
@@ -118,11 +126,13 @@ class DecodeExecutor:
         self.total_busy = 0.0
         self.speculated = 0          # deeper steps dispatched speculatively
         self.spec_hits = 0           # ... that the schedule then consumed
+        self.chained = 0             # steps resumed from the depth before
         self.readbacks = 0           # confidences read back in `commit`
         self.readbacks_overlapped = 0  # ... with a deeper step in flight
         self._running = None
         self._spec = None            # (token, stage, out, new_cache)
         self._done = None
+        self._done_at = None         # (token, stage) of `_done`
         self.chosen = None           # (out, new_cache) of the last commit
 
     # -- Executor contract ---------------------------------------------
@@ -133,25 +143,47 @@ class DecodeExecutor:
     def wcet(self, stage, n):
         return 0.0
 
+    def _dispatch(self, stage, pos, prev=None):
+        """Enqueue depth ``stage + 1``: from the token and the committed
+        cache, or resumed from ``prev``, depth ``stage``'s
+        ``(out, new_cache)``, whose exits and stage caches fill in what
+        the resumed step does not return."""
+        if prev is None:
+            out, new_cache = self.steps[stage](self.params, self.cache,
+                                               self.tok, pos)
+            out.confidences[-1].copy_to_host_async()
+            return out, new_cache
+        head, cache = prev
+        out, new_cache = self.steps[stage](
+            self.params, cache, self._from(head.h_final, stage), pos)
+        out.confidences[-1].copy_to_host_async()
+        self.chained += 1
+        out = dataclasses.replace(
+            out, logits=[*head.logits, *out.logits],
+            confidences=[*head.confidences, *out.confidences])
+        return out, [c if n is None else n for c, n in zip(cache, new_cache)]
+
     def submit(self, stage, tasks, now):
         jnp = self._jnp
         task = tasks[0]
         hit = self._spec is not None and self._spec[:2] == (task.sample, stage)
-        with span("repro.executor.launch", depth=stage + 1, hit=hit):
+        resume = (not hit and stage > 0
+                  and self._done_at == (task.sample, stage - 1))
+        ahead = self.speculate and stage + 1 < len(self.steps)
+        with span("repro.executor.launch", depth=stage + 1, hit=hit,
+                  chained=resume + ahead):
             pos = jnp.full((self.tok.shape[0],), task.sample, jnp.int32)
             if hit:
                 out, new_cache = self._spec[2:]
                 self.spec_hits += 1
             else:
-                out, new_cache = self.steps[stage](self.params, self.cache,
-                                                   self.tok, pos)
-                out.confidences[-1].copy_to_host_async()
+                out, new_cache = self._dispatch(
+                    stage, pos, self._done if resume else None)
             self._spec = None
-            if self.speculate and stage + 1 < len(self.steps):
-                o2, c2 = self.steps[stage + 1](self.params, self.cache,
-                                               self.tok, pos)
-                o2.confidences[-1].copy_to_host_async()
-                self._spec = (task.sample, stage + 1, o2, c2)
+            if ahead:
+                self._spec = (task.sample, stage + 1,
+                              *self._dispatch(stage + 1, pos,
+                                              (out, new_cache)))
                 self.speculated += 1
         self._running = (stage, tasks, out, new_cache, now)
 
@@ -165,6 +197,7 @@ class DecodeExecutor:
             self._jax.block_until_ready(out.logits[-1])
         self.total_busy += clock.now() - t0
         self._done = (out, new_cache)
+        self._done_at = (tasks[0].sample, stage)
         return stage, tasks
 
     def commit(self, task, k):
@@ -326,7 +359,8 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from repro.launch.compile_cache import enable_compile_cache
-    from repro.models import decode_step, init_decode_cache, init_params
+    from repro.models import (DecodeFrom, decode_step, init_decode_cache,
+                              init_params)
     from repro.training import checkpoint
 
     enable_compile_cache()
@@ -343,12 +377,17 @@ def main(argv=None):
 
     tok = (jnp.zeros((B, cfg.num_codebooks), jnp.int32)
            if cfg.modality == "audio_stub" else jnp.zeros((B,), jnp.int32))
-    # compile every depth before the clock starts (first call = compile +
-    # one step; the persistent cache turns a rerun's compile into a load)
+    # compile what the executor runs before the clock starts: depth 1 from
+    # the token, each deeper depth resumed from the one before (first call
+    # = compile + one step; the persistent cache turns a rerun's compile
+    # into a load)
     t0 = time.perf_counter()
     pos0 = jnp.zeros((B,), jnp.int32)
-    for step in steps:
-        jax.block_until_ready(step(params, cache, tok, pos0)[0].logits[-1])
+    out, _ = steps[0](params, cache, tok, pos0)
+    for d in range(1, n_stages):
+        out, _ = steps[d](params, cache, DecodeFrom(out.h_final, d), pos0)
+    jax.block_until_ready(out.logits[-1])
+    del out
     compile_s = time.perf_counter() - t0
     print(f"{cfg.name}: d_model={cfg.d_model} layers={cfg.num_layers} "
           f"vocab={cfg.vocab_size} dtype={cfg.dtype} params={params_gb:.3f}GB "

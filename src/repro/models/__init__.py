@@ -1,6 +1,7 @@
 from repro.models.common import ParallelCtx
 from repro.models.exits import exit_rows, exit_stats_fused, exit_stats_unfused
 from repro.models.model import (
+    DecodeFrom,
     ExitsOut,
     concat_decode_caches,
     count_params_analytic,
@@ -16,7 +17,7 @@ from repro.models.model import (
 )
 
 __all__ = [
-    "ParallelCtx", "ExitsOut", "concat_decode_caches",
+    "ParallelCtx", "DecodeFrom", "ExitsOut", "concat_decode_caches",
     "count_params_analytic", "decode_step",
     "exit_rows", "exit_stats_fused", "exit_stats_unfused",
     "forward", "init_decode_cache", "init_params", "slice_decode_cache",

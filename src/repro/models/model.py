@@ -12,6 +12,7 @@ Public API
 init_params(cfg, key)                  -> params pytree
 forward(cfg, params, inputs, ...)      -> ExitsOut (train / prefill)
 decode_step(cfg, params, cache, ...)   -> (exits, new_cache)
+DecodeFrom(h, stage)                   -> decode_step's resume carry
 init_decode_cache(cfg, batch, slots)   -> cache pytree
 stage_forward / stage_decode_step      -> the scheduler's dispatch unit
 count_params_analytic(cfg)             -> N (for roofline MODEL_FLOPS)
@@ -470,21 +471,44 @@ def concat_decode_caches(st_caches):
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class DecodeFrom:
+    """Where a decode step resumes: ``h`` is the (B, d) hidden state that
+    stage ``stage`` takes as input — a shallower step's ``h_final``.
+    ``stage`` is static, so each resume point is its own jitted program."""
+    h: Any
+    stage: int
+
+
+jax.tree_util.register_dataclass(DecodeFrom, data_fields=["h"],
+                                 meta_fields=["stage"])
+
+
 def decode_step(cfg, params, cache, token, cur_pos, *, ctx=None,
                 upto_stage=None, conf_temperature=1.0):
     """One decode step through (up to) `upto_stage` stages.
 
-    token: (B,) int32 (or (B,ncb) audio); cur_pos: (B,) int32 positions.
-    Returns (ExitsOut with last-position logits per stage, new_cache).
+    token: (B,) int32 (or (B,ncb) audio), or a :class:`DecodeFrom` to
+    resume at a stage boundary: the embedding and stages before
+    ``token.stage`` are skipped, so a step of depth d+1 continues depth
+    d's step from its ``h_final`` and new cache.  cur_pos: (B,) int32.
+    Returns (ExitsOut with last-position logits per stage run, new_cache).
+    From a token, new_cache forwards the stages not run; a resumed step
+    returns None in their place, for the caller holds them already: a
+    jitted output that is an unchanged input is a full copy on the device.
     """
     layouts = stage_layouts(cfg)
     n_stages = len(layouts) if upto_stage is None else upto_stage
-    h = embed_one(cfg, params["embed"], token, cur_pos)      # (B, d)
+    if isinstance(token, DecodeFrom):
+        first, h = token.stage, token.h
+        new_cache = [None] * len(layouts)
+    else:
+        first, h = 0, embed_one(cfg, params["embed"], token, cur_pos)
+        new_cache = list(cache)
     if ctx is not None:
-        h = shard(h, ctx, ctx.dp, None)
+        h = shard(h, ctx, ctx.dp, None)                      # (B, d)
     logits_list, conf_list = [], []
-    new_cache = [None] * len(layouts)
-    for s in range(n_stages):
+    for s in range(first, n_stages):
         h, st_cache = _stage_decode(cfg, params["stages"][s], layouts[s],
                                     cache[s], h, cur_pos, ctx)
         new_cache[s] = st_cache
@@ -497,8 +521,6 @@ def decode_step(cfg, params, cache, token, cur_pos, *, ctx=None,
         while conf.ndim > 1:
             conf = conf.mean(-1)
         conf_list.append(conf)
-    for s in range(n_stages, len(layouts)):
-        new_cache[s] = cache[s]
     return ExitsOut(logits_list, conf_list, jnp.zeros((), jnp.float32),
                     h, None), new_cache
 

@@ -22,7 +22,8 @@ Names are ``repro.<layer>.<what>``:
                                a pre-selection's re-validation
 ``repro.source.advance``       the token loop's cache swap and sampling
 ``repro.executor.launch``      everything in ``submit`` that enqueues device
-                               work
+                               work (``decode``: metadata ``depth``,
+                               ``hit``, ``chained``)
 ``repro.executor.stage_inputs``  ``StagingBuffers.stage`` (host batch rows)
 ``repro.executor.wait``        blocking on the device in ``complete``
 ``repro.executor.readback``    device→host reads of a window's results; the
