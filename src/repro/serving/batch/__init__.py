@@ -6,18 +6,13 @@ Layers (see ROADMAP.md "Serving architecture"):
   policy      BatchPolicy contract + BatchedPolicy adapter        [no jax]
   admission   AdmissionController (reject / depth-cap)            [no jax]
   stage_fns   padded, shape-bucketed jitted stage functions
-  engine      BatchedServingEngine (wall clock)
-  simulator   simulate_batched (discrete event) — same policies,
-              same batch semantics as the wall-clock path
 """
 from repro.serving.batch.admission import (AdmissionController,
                                            AdmissionDecision)
 from repro.serving.batch.batcher import (DEFAULT_BUCKETS, BatchTimeModel,
                                          StageBatcher, bucket_for)
-from repro.serving.batch.engine import BatchedServingEngine
 from repro.serving.batch.policy import (BatchedPolicy, BatchPolicy,
                                         as_batch_policy)
-from repro.serving.batch.simulator import simulate_batched
 from repro.serving.batch.stage_fns import (BatchedStageFns, StagingBuffers,
                                            pad_batch,
                                            profile_batched_stages,
@@ -29,10 +24,10 @@ from repro.serving.batch.time_model import (DEFAULT_LEN_BUCKETS,
 
 __all__ = [
     "AdmissionController", "AdmissionDecision", "BatchTimeModel",
-    "BatchedPolicy", "BatchPolicy", "BatchedServingEngine",
+    "BatchedPolicy", "BatchPolicy",
     "BatchedStageFns", "DEFAULT_BUCKETS", "DEFAULT_LEN_BUCKETS",
     "LengthBucketTimeModel", "StageBatcher", "StagingBuffers",
     "as_batch_policy", "batch_wcet", "bucket_for", "len_bucket_for",
-    "pad_batch", "profile_batched_stages", "simulate_batched",
+    "pad_batch", "profile_batched_stages",
     "split_rows", "task_len_bucket",
 ]

@@ -5,10 +5,10 @@ Contract
 ``next_batch(active, now) -> Optional[(stage, [tasks])]``
 
 Everything else (``on_arrival`` / ``on_stage_done`` / ``sched_time``)
-is inherited from the single-task ``Policy`` interface, so the batched
-engine and ``simulate_batched`` drive exactly the policies the paper
-evaluates — RTDeepIoT, EDF, LCF, RR — with batch *composition* layered on
-top of each policy's dispatch preference:
+is inherited from the single-task ``Policy`` interface, so the serving
+loop drives exactly the policies the paper evaluates — RTDeepIoT, EDF,
+LCF, RR — with batch *composition* layered on top of each policy's
+dispatch preference:
 
 * the base policy still picks the **leader** (its ``next_task`` order:
   planned-EDF for RTDeepIoT, deadline for EDF, lowest confidence for LCF,

@@ -28,7 +28,7 @@ from repro.serving.obs.hostspans import span
 class StagingBuffers:
     """Reused per-bucket host staging for batch formation.
 
-    ``pad_batch`` used to re-stack the per-sample pytrees into fresh
+    ``pad_batch`` alone re-stacks the per-sample pytrees into fresh
     device arrays on every dispatch — a per-dispatch allocation (and a
     jitted concatenate) on the hot path.  A ``StagingBuffers`` instance
     instead keeps one pinned numpy buffer per (bucket, leaf-struct): rows
@@ -144,8 +144,9 @@ def profile_batched_stages(cfg, params, fns: BatchedStageFns, sample_input, *,
                            n_runs: int = 30, percentile: float = 99.0):
     """Profile the (num_stages, num_buckets) batched-stage WCET matrix.
 
-    Mirrors ``repro.serving.profile_stages`` (99th-percentile over timed
-    runs), one column per batch-size bucket.  Returns
+    Each entry is the ``percentile`` of ``n_runs`` timed runs (paper §IV:
+    99% upper bound over profiling runs), one column per batch-size
+    bucket; stage s+1 runs on stage s's output.  Returns
     ``(BatchTimeModel, matrix)``."""
     L = cfg.num_stages
     mat = np.zeros((L, len(fns.buckets)))

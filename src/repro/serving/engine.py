@@ -1,25 +1,15 @@
-"""RTDeepIoT serving engine (paper Fig. 2) — user-space, wall-clock.
+"""Request types and host-side helpers of the serving loop.
 
-The engine owns:
-  * per-stage jitted functions (repro.models.stage_forward) — the
-    non-preemptive dispatch units;
-  * profiled per-stage WCETs (99th-percentile, paper §IV protocol);
-  * a scheduling Policy (RTDeepIoT or a baseline).
+* ``Request`` / ``Response`` — what a stream source admits (input pytree,
+  relative deadline, sample / client / SLO / tenant labels) and what the
+  device executors answer (deepest in-time exit, latency, miss).
+* ``closed_loop_stream`` — the paper's K-client workload laid out as an
+  ``(offset_seconds, Request)`` stream for the ``stream`` source.
+* ``profile_host_overhead`` — the per-dispatch host cost behind the §II-B
+  deadline adjustment (``ServeSpec.host_overhead``).
 
-Requests (input pytree + absolute wall deadline) enter a queue; the engine
-loop dispatches one stage at a time on the accelerator, returns each stage's
-(prediction, confidence) to the policy between stages — the user-space
-decision point the paper argues for — and responds with the deepest in-time
-exit when a task completes its assigned depth or its deadline expires.
-
-Deadline adjustment (§II-B): the caller-visible deadline is reduced by the
-profiled host/dispatch overhead and one worst-case stage time (the
-non-preemptible region) before it reaches the scheduler.
-
-``run`` is a deprecated wrapper over the public serving facade
-(``repro.serving.service``): a ``ServeSpec`` on the ``device-single``
-executor / wall clock / stream source, dispatching singleton batches
-(``batching={"mode": "none"}``).
+The loop itself is ``repro.serving.runtime.EngineCore``, built through
+``repro.serving.Service``.
 """
 from __future__ import annotations
 
@@ -29,8 +19,6 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
-
-from repro.models import stage_forward
 
 
 @dataclasses.dataclass
@@ -58,45 +46,6 @@ class Response:
     deadline: float
 
 
-def make_stage_fns(cfg):
-    """Jitted per-stage functions: stage 0 embeds raw inputs, later stages
-    consume hidden states.  Returns list of fn(params, x) -> (h, logits,
-    conf)."""
-    fns = []
-    for s in range(cfg.num_stages):
-        def fn(params, h, _s=s):
-            return stage_forward(cfg, params, _s, h, mode="train")
-        fns.append(jax.jit(fn))
-    return fns
-
-
-def profile_stages(cfg, params, stage_fns, sample_inputs, *, n_runs: int = 100,
-                   percentile: float = 99.0, sync=True):
-    """Per-stage WCET = `percentile` of `n_runs` timed executions (paper:
-    99% CI upper bound over profiling runs on training data).
-
-    Also measures the host dispatch overhead (round-trip time of a no-op jit
-    call) used for the §II-B deadline adjustment.  Returns
-    ``(wcet, times, host_overhead)``; pass the overhead straight into
-    ``ServingEngine(host_overhead=...)``.
-    """
-    times = np.zeros((cfg.num_stages, n_runs))
-    h = sample_inputs
-    for s, fn in enumerate(stage_fns):
-        out = fn(params, h)                        # compile
-        jax.block_until_ready(out[0])
-        for i in range(n_runs):
-            t0 = time.perf_counter()
-            out = fn(params, h)
-            jax.block_until_ready(out[0])
-            times[s, i] = time.perf_counter() - t0
-        h = out[0]
-    wcet = np.percentile(times, percentile, axis=1)
-    host_overhead = profile_host_overhead(n_runs=n_runs,
-                                          percentile=percentile)
-    return wcet, times, host_overhead
-
-
 def profile_host_overhead(*, n_runs: int = 100,
                           percentile: float = 99.0) -> float:
     """Host dispatch overhead: round-trip of a no-op jitted call (§II-B).
@@ -112,42 +61,6 @@ def profile_host_overhead(*, n_runs: int = 100,
         jax.block_until_ready(noop(z))
         samples[i] = time.perf_counter() - t0
     return float(np.percentile(samples, percentile))
-
-
-class ServingEngine:
-    def __init__(self, cfg, params, policy, *, stage_wcet,
-                 host_overhead: float = 0.0, stage_fns=None):
-        self.cfg = cfg
-        self.params = params
-        self.policy = policy
-        self.stage_fns = stage_fns or make_stage_fns(cfg)
-        self.stage_wcet = tuple(float(x) for x in stage_wcet)
-        self.host_overhead = host_overhead
-        self.responses: list = []
-
-    # ------------------------------------------------------------------
-    def run(self, request_stream):
-        """request_stream: iterable of (offset_seconds, Request), offsets
-        non-decreasing relative to engine start."""
-        from repro.serving.deprecation import deprecate_once
-        from repro.serving.service import ServeSpec, Service
-
-        deprecate_once(
-            "repro.serving.ServingEngine.run",
-            "ServingEngine is deprecated: build a ServeSpec(executor="
-            "'device-single', clock='wall', source='stream') and run it "
-            "through repro.serving.Service instead")
-        spec = ServeSpec(
-            executor="device-single", clock="wall", source="stream",
-            batching={"mode": "none",
-                      "stage_times": [float(x) for x in self.stage_wcet]},
-            host_overhead=self.host_overhead)
-        svc = Service.from_spec(spec, policy=self.policy, cfg=self.cfg,
-                                params=self.params,
-                                stage_fns=self.stage_fns)
-        svc.run(request_stream)
-        self.responses.extend(svc.responses)
-        return self.responses
 
 
 def closed_loop_stream(dataset_inputs, labels, *, n_clients, d_lo, d_hi,

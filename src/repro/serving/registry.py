@@ -33,8 +33,8 @@ policy    ``rtdeepiot`` (predictor/prior_curve/delta/oracle via args),
           ``rtdeepiot-weighted`` (same + ``Task.weight``-aware dispatch
           and batch seating), ``edf``, ``lcf``, ``rr``
 executor  ``oracle`` (conf tables + BatchTimeModel),
-          ``device-single`` (per-stage jitted fns, singleton dispatch),
-          ``device-batched`` (bucketed BatchedStageFns)
+          ``device-batched`` (bucketed BatchedStageFns; singleton
+          dispatch under ``batching={"mode": "none"}``)
 clock     ``virtual`` (discrete event), ``wall`` (real time)
 source    ``closed-loop`` (§IV K-client workload), ``stream``
           ((offset, Request) list), ``live`` (``Service.submit`` queue)
@@ -215,39 +215,21 @@ def _make_wall(args, ctx):
 @register_executor("oracle")
 def _make_oracle(args, ctx):
     from repro.serving.runtime.executor import OracleExecutor
+    conf = ctx.resources["conf_table"]
+    if ctx.time_model.num_stages != conf.shape[1]:
+        raise ValueError(f"time model has {ctx.time_model.num_stages} "
+                         f"stages, oracle tables have {conf.shape[1]}")
     # pipeline_depth >= 3 enqueues depth-1 virtual device windows, same
     # scaling as the device executors (one running + the rest queued)
     return OracleExecutor(
-        ctx.time_model, ctx.resources["conf_table"],
+        ctx.time_model, conf,
         max_inflight=max(1, int(ctx.spec.pipeline_depth) - 1))
-
-
-@register_executor("device-single")
-def _make_device_single(args, ctx):
-    """Per-stage jitted fns, singleton dispatch (the legacy ServingEngine
-    device).  resources: cfg, params, optionally stage_fns (fn list)."""
-    import jax
-
-    from repro.serving.engine import make_stage_fns
-    from repro.serving.runtime.device import DeviceExecutor, SingleStageFns
-    cfg, params = ctx.resources["cfg"], ctx.resources["params"]
-    fns = ctx.resources.get("stage_fns") or make_stage_fns(cfg)
-    ex = DeviceExecutor(SingleStageFns(fns), params, ctx.time_model)
-
-    def warmup(sample_input):
-        h = sample_input
-        for fn in fns:
-            out = fn(params, h)
-            jax.block_until_ready(out[0])
-            h = out[0]
-    ex.warmup = warmup
-    return ex
 
 
 @register_executor("device-batched")
 def _make_device_batched(args, ctx):
-    """Bucketed batched stage fns (the legacy BatchedServingEngine device).
-    resources: cfg, params, optionally stage_fns (BatchedStageFns)."""
+    """Bucketed batched stage fns.  resources: cfg, params, optionally
+    stage_fns (BatchedStageFns)."""
     from repro.serving.batch.stage_fns import BatchedStageFns
     from repro.serving.runtime.device import DeviceExecutor
     cfg, params = ctx.resources["cfg"], ctx.resources["params"]

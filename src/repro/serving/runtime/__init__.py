@@ -1,4 +1,4 @@
-"""Unified async event-driven serving runtime (one loop, four faces).
+"""Unified async event-driven serving runtime (one loop).
 
 ``EngineCore`` is the single implementation of the paper's user-space
 scheduling loop — admit → expire → dispatch → observe → retire, §II-B
@@ -16,22 +16,13 @@ RequestSource          ``ClosedLoopSource``      ``StreamSource``
                        (K clients, §IV)          ((offset, Request) list)
 =====================  ========================  =========================
 
-New callers do not wire these axes by hand: the public front door is
+Callers do not wire these axes by hand: the front door is
 ``repro.serving.service`` — a declarative ``ServeSpec`` resolved through
-``repro.serving.registry`` builds the ``EngineCore``.  The legacy entry
-points are deprecated wrappers over that facade (all public signatures
-unchanged, one-shot ``DeprecationWarning`` each):
+``repro.serving.registry`` builds the ``EngineCore``.  Unbatched serving
+is ``ServeSpec(batching={"mode": "none", ...})``: single-bucket pricing,
+every dispatch a singleton.
 
-* ``repro.core.simulate``            → ``ServeSpec(batching={"mode":
-  "none", ...})`` — single-bucket pricing, every dispatch a singleton.
-* ``repro.serving.batch.simulate_batched`` → ``ServeSpec`` with the
-  caller's time model / admission controller / ``max_batch``.
-* ``repro.serving.ServingEngine.run``      → ``ServeSpec(executor=
-  "device-single", clock="wall", source="stream")``.
-* ``repro.serving.batch.BatchedServingEngine.run`` → ``ServeSpec(
-  executor="device-batched", clock="wall", source="stream")``.
-
-Runtime-only capabilities on top of the unified core:
+Capabilities of the core:
 
 * ``pipeline_depth=2`` — pipelined async dispatch: the host pre-selects
   batch *N+1* while batch *N* runs on the device, re-validating deadline
@@ -40,14 +31,14 @@ Runtime-only capabilities on top of the unified core:
   charged-overhead comparisons are reproducible.
 * unified host-cost accounting (``sched_charged`` / ``host_serial`` /
   ``host_overhead_frac`` / ``n_dispatches`` on ``SimResult``) on every
-  path, fixing the legacy ``simulate_batched`` dropping charged time.
+  path.
 
 ``DeviceExecutor`` lives in ``repro.serving.runtime.device`` (imports jax);
 everything imported here is numpy-only so the simulators stay light.
 """
 from repro.serving.runtime.clock import Clock, VirtualClock, WallClock
 from repro.serving.runtime.core import (EngineCore, ResponseRecorder,
-                                        TableRecorder, simulate_runtime)
+                                        TableRecorder)
 from repro.serving.runtime.executor import Executor, OracleExecutor
 from repro.serving.runtime.sources import (ClosedLoopSource, RequestSource,
                                            StreamSource)
@@ -55,5 +46,5 @@ from repro.serving.runtime.sources import (ClosedLoopSource, RequestSource,
 __all__ = [
     "Clock", "ClosedLoopSource", "EngineCore", "Executor", "OracleExecutor",
     "RequestSource", "ResponseRecorder", "StreamSource", "TableRecorder",
-    "VirtualClock", "WallClock", "simulate_runtime",
+    "VirtualClock", "WallClock",
 ]

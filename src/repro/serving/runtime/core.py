@@ -4,9 +4,8 @@ admit → expire → dispatch → observe → retire, parameterized by a
 :class:`~repro.serving.runtime.clock.Clock` (virtual vs wall time), an
 :class:`~repro.serving.runtime.executor.Executor` (oracle tables vs real
 jitted stages) and a :class:`~repro.serving.runtime.sources.RequestSource`
-(closed-loop clients vs a request stream).  The four legacy entry points
-(``simulate``, ``simulate_batched``, ``ServingEngine``,
-``BatchedServingEngine``) are thin configurations of this loop.
+(closed-loop clients vs a request stream).  ``repro.serving.Service``
+builds it from a ``ServeSpec``; nothing else constructs it.
 
 Pipelined async dispatch (``pipeline_depth=2``): with synchronous dispatch
 the host blocks on the device, so every piece of host work — policy
@@ -46,7 +45,6 @@ import numpy as np
 
 from repro.core.simulator import SimResult
 from repro.serving.batch.batcher import StageBatcher
-from repro.serving.batch.policy import as_batch_policy
 from repro.serving.obs.hostspans import span
 
 _EPS = 1e-12
@@ -446,34 +444,3 @@ class EngineCore:
             self._retire(t, tend)
         self.makespan = makespan
         return self.recorder
-
-
-def simulate_runtime(policy, workload, time_model, conf_table, correct_table,
-                     *, charge_overhead: bool = False,
-                     dispatch_overhead: float = 0.0, admission=None,
-                     max_batch: int = None, pipeline_depth: int = 1,
-                     policy_cost=None) -> SimResult:
-    """Discrete-event run of the unified core over oracle tables.
-
-    ``simulate`` (unbatched: single-bucket time model, ``max_batch=1``) and
-    ``simulate_batched`` are this with ``pipeline_depth=1``; pipelined
-    async dispatch and deterministic host-cost models are runtime-only.
-    """
-    from repro.serving.runtime.clock import VirtualClock
-    from repro.serving.runtime.executor import OracleExecutor
-    from repro.serving.runtime.sources import ClosedLoopSource
-
-    pol = as_batch_policy(policy, time_model, max_batch=max_batch)
-    core = EngineCore(
-        pol, VirtualClock(charge_overhead=charge_overhead),
-        OracleExecutor(time_model, conf_table,
-                       max_inflight=max(1, pipeline_depth - 1)),
-        ClosedLoopSource(workload, conf_table.shape[0],
-                         time_model.single_times()),
-        TableRecorder(conf_table, correct_table),
-        admission=admission, pipeline_depth=pipeline_depth,
-        dispatch_overhead=dispatch_overhead, policy_cost=policy_cost,
-        max_batch=min(max_batch or time_model.max_batch,
-                      time_model.max_batch))
-    recorder = core.run()
-    return recorder.result(core)

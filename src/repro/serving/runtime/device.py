@@ -3,8 +3,8 @@
 ``submit`` dispatches the batched stage *without* blocking (XLA dispatch is
 asynchronous), so with ``pipeline_depth=2`` the core pre-selects the next
 batch on the host while the device computes; ``complete`` blocks on the
-results and reads the wall clock for the completion time, exactly the
-instant the legacy engines stamped after ``block_until_ready``.
+results and reads the wall clock for the completion time, right after
+``block_until_ready``.
 
 Multiple in-flight windows: the executor accepts up to ``max_inflight``
 submitted-but-uncompleted batches (a FIFO — XLA executes dispatches in
@@ -15,18 +15,18 @@ oldest window; ``running_tasks`` covers every queued window so the core
 never double-dispatches an in-flight task.
 
 Per-request state (input/hidden pytree, deepest in-time exit) lives here:
-the executor is the layer that owns device data, so the engines' old
-``_states`` dict moves in with it.  That dict is the serving stack's
-hidden-state cache: a request's state is registered at admission,
-**persisted across stage dispatches** (each ``commit`` slices the
-request's row out of the batched stage output — a device-resident array,
-never copied to host between stages) and **evicted on retire** (the
-recorder pops it via ``pop_state``).  ``cache_stats()`` reports
-live/peak/evicted counts so tests and metrics can hold the cache to that
-lifecycle.  ``ShardedDeviceExecutor`` (:mod:`repro.launch.sharded`) runs
-the same contract with stage fns sharded over a device mesh;
-``KernelDeviceExecutor`` (:mod:`repro.launch.kernel`) swaps the stage
-bodies for Pallas-kernel-backed fns.
+the executor is the layer that owns device data.  Its ``states`` dict is
+the serving stack's hidden-state cache: a request's state is registered
+at admission, **persisted across stage dispatches** (each ``commit``
+slices the request's row out of the batched stage output — a
+device-resident array, never copied to host between stages) and
+**evicted on retire** (the recorder pops it via ``pop_state``).
+``cache_stats()`` reports live/peak/evicted counts so tests and metrics
+can hold the cache to that lifecycle.  ``ShardedDeviceExecutor``
+(:mod:`repro.launch.sharded`) runs the same contract with stage fns
+sharded over a device mesh; ``KernelDeviceExecutor``
+(:mod:`repro.launch.kernel`) swaps the stage bodies for
+Pallas-kernel-backed fns.
 
 Telemetry: per-stage host seconds (synchronous dispatch + commit work,
 measured on ``perf_counter`` so it is meaningful under any engine clock)
@@ -44,18 +44,6 @@ import jax
 import numpy as np
 
 from repro.serving.obs.hostspans import span
-
-
-class SingleStageFns:
-    """Adapt the unbatched engine's per-stage ``fn(params, h)`` list to the
-    batched ``run(stage, params, pytrees)`` surface (batches of exactly 1)."""
-
-    def __init__(self, fns):
-        self.fns = fns
-
-    def run(self, stage: int, params, pytrees):
-        h, logits, conf = self.fns[stage](params, pytrees[0])
-        return h, logits, conf, np.ones(1, bool)
 
 
 class DeviceExecutor:

@@ -29,10 +29,8 @@ built from it owns the engine lifecycle:
   (per-class breakdown, admission/cancellation counts), JSON-exportable.
 * graceful ``drain()`` / ``close()``.
 
-The four legacy faces (``simulate``, ``simulate_batched``,
-``ServingEngine``, ``BatchedServingEngine``) are deprecated thin wrappers
-over this facade; their fixed-seed golden-parity results are preserved
-bit-for-bit (tests/test_runtime.py).
+Fixed-seed golden-parity results of the discrete-event paths are pinned
+bit-for-bit in tests/test_runtime.py.
 """
 from __future__ import annotations
 
@@ -103,8 +101,8 @@ class ServeSpec:
     ``batching`` describes the ``BatchTimeModel`` and batch discipline:
 
     * ``{"mode": "none", "stage_times": [...]}`` — singleton dispatch,
-      single-bucket pricing, legacy unbatched accounting (formation time
-      not billed) — exactly the old ``simulate``/``ServingEngine``.
+      single-bucket pricing, unbatched accounting (formation time not
+      billed) — the paper's Fig. 2 loop.
     * ``{"buckets": [...], "stage_times": [...], "marginal": 0.15}`` —
       analytic linear model (``BatchTimeModel.linear``).
     * ``{"buckets": [...], "times": [[...]]}`` — explicit per-bucket WCET

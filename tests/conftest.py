@@ -1,4 +1,5 @@
 import jax
+import numpy as np
 import pytest
 
 # Tests run single-device on CPU (the 512-device dry-run is subprocess-only,
@@ -75,6 +76,26 @@ def wait_until(predicate, timeout=10.0, interval=0.005, desc="condition"):
             return v
         time.sleep(interval)
     raise AssertionError(f"timed out after {timeout}s waiting for {desc}")
+
+
+def oracle_tables(n, L=3, seed=0):
+    """Synthetic per-sample oracle tables ``(conf, correct)``, each
+    ``(n, L)``: confidence rises with depth in [0.3, 1.0), and a stage's
+    answer is correct with probability equal to its confidence."""
+    rng = np.random.default_rng(seed)
+    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
+    correct = rng.uniform(size=(n, L)) < conf
+    return conf, correct.astype(bool)
+
+
+def serve_closed_loop(spec, policy, workload, conf, correct, **resources):
+    """Run ``spec`` through ``Service`` on the §IV closed-loop
+    ``workload``, with ``policy`` (an instance, so the registry is
+    skipped) deciding over the oracle tables."""
+    from repro.serving import Service
+    return Service.from_spec(spec, policy=policy, workload=workload,
+                             conf_table=conf, correct_table=correct,
+                             **resources).run()
 
 
 @pytest.fixture(scope="session")

@@ -40,6 +40,8 @@ from repro.serving.registry import available
 from repro.serving.traffic import load_trace, make_arrival_process
 from repro.serving.traffic.scenarios import scenario_spec
 
+from conftest import oracle_tables
+
 STAGE_TIMES = (0.004, 0.007, 0.010)
 
 ARRIVAL_CONFIGS = {
@@ -49,13 +51,6 @@ ARRIVAL_CONFIGS = {
     "flash-crowd": dict(base_rate=60.0, spike_rate=400.0, spike_at=1.0,
                         spike_len=1.0),
 }
-
-
-def oracle_tables(n=200, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 def sample_offsets(kind, seed=0, n=2000):
@@ -85,7 +80,7 @@ def test_extract_offsets_per_request_rows_prefer_offset():
 
 
 def test_extract_offsets_from_recorded_trace(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("steady", stage_times=STAGE_TIMES, n_requests=40,
                          seed=1)
     res = Service.from_spec(spec, conf_table=conf,
@@ -221,7 +216,7 @@ def test_fit_diurnal_period_recovery_property(period, seed):
 # ---------------------------------------------------------------------------
 
 def test_estimator_converges_to_oracle_mean():
-    oracle, _ = oracle_tables(n=600)
+    oracle, _ = oracle_tables(600)
     est = OnlineCurveEstimator(num_stages=3, prior_weight=0.0)
     for row in oracle:
         est.observe_exits(row)
@@ -334,7 +329,7 @@ def test_adaptive_predictor_suffix_is_monotone_and_anchored():
 
 def test_adaptive_policy_is_registered_and_learns_through_service():
     assert "rtdeepiot-adaptive" in available("policy")
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     est = OnlineCurveEstimator(num_stages=3, prior=conf.mean(0))
     spec = scenario_spec("steady", policy="rtdeepiot-adaptive",
                          stage_times=STAGE_TIMES, n_requests=60, seed=2)
@@ -480,7 +475,7 @@ def test_spec_validates_forecast_shape():
 def test_forecast_decisions_reach_the_audit_log():
     """End-to-end: a flash-crowd run with a fitted forecast leaves
     forecast-capped rows in the obs audit log, numbers attached."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     nominal = 1.0 / sum(STAGE_TIMES)
     fc = {"process": {"kind": "flash-crowd", "base_rate": 0.2 * nominal,
                       "spike_rate": 5.0 * nominal, "spike_at": 0.3,
@@ -501,7 +496,7 @@ def test_forecast_decisions_reach_the_audit_log():
 
 
 def test_forecast_only_admission_defaults_to_depth_cap():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     nominal = 1.0 / sum(STAGE_TIMES)
     fc = {"process": {"kind": "poisson", "rate": 3.0 * nominal}}
     spec = scenario_spec("steady", stage_times=STAGE_TIMES, n_requests=40,
@@ -556,7 +551,7 @@ def test_driver_argument_validation():
 @pytest.mark.wallclock
 def test_driver_paces_submissions_into_a_live_service():
     from conftest import wait_until
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     with Service.from_spec(_live_spec(), conf_table=conf,
                            correct_table=correct) as svc:
         drv = TrafficDriver(svc, arrival={"kind": "poisson", "rate": 200.0},
@@ -572,7 +567,7 @@ def test_driver_paces_submissions_into_a_live_service():
 
 @pytest.mark.wallclock
 def test_driver_stop_aborts_pacing_quickly():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     with Service.from_spec(_live_spec(), conf_table=conf,
                            correct_table=correct) as svc:
         # 10 rps unscaled: the full stream would take ~2s; stop instead
@@ -587,7 +582,7 @@ def test_driver_stop_aborts_pacing_quickly():
 
 @pytest.mark.wallclock
 def test_driver_replays_a_recorded_trace_live(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("steady", stage_times=STAGE_TIMES, n_requests=12,
                          seed=5)
     res = Service.from_spec(spec, conf_table=conf,
@@ -616,7 +611,7 @@ def test_fitted_forecast_arms_admission_from_yesterdays_trace():
     """The adaptive story end to end on the virtual clock: record a
     flash-crowd day, fit it, arm admission with the fit, and replay a
     different seed of the same process — the forecast rule fires."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     # enough requests that the spike *ends* inside the trace — on a
     # spike-truncated record an on/off MMPP explains the data just as well
     rec_spec = scenario_spec("flash-crowd", stage_times=STAGE_TIMES,

@@ -2,21 +2,21 @@
 (repro.serving.batch): padded batched stage functions match per-sample
 outputs, batch formation never violates a member's deadline, admission
 control, the closed-loop reissue semantics, and a deterministic
-simulate_batched run that strictly beats the unbatched simulator."""
+micro-batched closed-loop run that strictly beats unbatched serving."""
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import EDF, LCF, RTDeepIoT, Task, Workload, make_predictor, simulate
+from repro.core import EDF, LCF, RTDeepIoT, Task, Workload, make_predictor
 from repro.models import init_params, stage_forward
+from repro.serving import ServeSpec
 from repro.serving.batch import (AdmissionController, BatchedPolicy,
                                  BatchTimeModel, StageBatcher,
-                                 as_batch_policy, bucket_for, pad_batch,
-                                 simulate_batched)
+                                 as_batch_policy, bucket_for, pad_batch)
 from repro.serving.batch.stage_fns import BatchedStageFns, split_rows
 
-from conftest import make_inputs
+from conftest import make_inputs, oracle_tables, serve_closed_loop
 
 
 def mk_task(deadline, times=(0.004, 0.007, 0.010), executed=0, mandatory=1,
@@ -27,13 +27,6 @@ def mk_task(deadline, times=(0.004, 0.007, 0.010), executed=0, mandatory=1,
     t.assigned_depth = t.num_stages
     t.confidences = list(confs)
     return t
-
-
-def oracle_tables(n=600, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 # ---------------------------------------------------------------------------
@@ -313,10 +306,12 @@ def test_batched_stage_fns_staging_results_stable(anytime_model):
 def test_closed_loop_reissues_at_completion():
     """One client, huge deadlines: request i+1 must be issued right when
     request i completes, not when its deadline would have expired."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     wl = Workload(n_clients=1, d_lo=1.0, d_hi=1.0, n_requests=5, seed=3)
     st = (0.004, 0.007, 0.010)
-    res = simulate(EDF(), wl, st, conf, correct)
+    res = serve_closed_loop(
+        ServeSpec(batching={"mode": "none", "stage_times": st}), EDF(), wl,
+        conf, correct)
     assert res.miss_rate == 0.0
     arrivals = sorted(f["arrival"] for f in res.per_request)
     gaps = np.diff(arrivals)
@@ -326,13 +321,13 @@ def test_closed_loop_reissues_at_completion():
 
 
 # ---------------------------------------------------------------------------
-# simulate_batched: batching strictly beats unbatched serving
+# closed loop: batching strictly beats unbatched serving
 # ---------------------------------------------------------------------------
 
 def test_batched_sim_beats_unbatched_throughput():
     """Deterministic overload run: the batched path sustains >= 3x the
     goodput of the unbatched path at no-worse miss rate and accuracy."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     st = (0.004, 0.007, 0.010)
     tm = BatchTimeModel.linear(st, (1, 2, 4, 8, 16), marginal=0.15)
     wl = Workload(n_clients=64, d_lo=0.01, d_hi=0.3, n_requests=500, seed=0)
@@ -340,8 +335,11 @@ def test_batched_sim_beats_unbatched_throughput():
     def policy():
         return RTDeepIoT(make_predictor("exp", prior_curve=conf.mean(0)))
 
-    res_u = simulate(policy(), wl, st, conf, correct)
-    res_b = simulate_batched(policy(), wl, tm, conf, correct)
+    res_u = serve_closed_loop(
+        ServeSpec(batching={"mode": "none", "stage_times": st}), policy(),
+        wl, conf, correct)
+    res_b = serve_closed_loop(ServeSpec(), policy(), wl, conf, correct,
+                              time_model=tm)
     assert res_b.throughput >= 3.0 * res_u.throughput, \
         f"batched {res_b.throughput:.1f} req/s vs unbatched " \
         f"{res_u.throughput:.1f} req/s"
@@ -352,24 +350,35 @@ def test_batched_sim_beats_unbatched_throughput():
 def test_batched_sim_respects_wrapped_policy_depth():
     """Batched EDF still serves every request to full depth when load is
     light — batching must not change *what* is computed, only how."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     st = (0.001, 0.001, 0.001)
     tm = BatchTimeModel.linear(st, (1, 2, 4), marginal=0.1)
     wl = Workload(n_clients=2, d_lo=0.5, d_hi=0.5, n_requests=20, seed=1)
-    res = simulate_batched(EDF(), wl, tm, conf, correct)
+    res = serve_closed_loop(ServeSpec(), EDF(), wl, conf, correct,
+                            time_model=tm)
     assert res.miss_rate == 0.0
     assert res.mean_depth == pytest.approx(3.0)
+
+
+def test_oracle_executor_rejects_stage_count_mismatch():
+    conf, correct = oracle_tables(600)
+    tm = BatchTimeModel.linear((0.004, 0.007), (1, 2))
+    wl = Workload(n_clients=2, n_requests=4, seed=0)
+    with pytest.raises(ValueError, match="time model has 2 stages"):
+        serve_closed_loop(ServeSpec(), EDF(), wl, conf, correct,
+                          time_model=tm)
 
 
 def test_batched_sim_admission_reduces_wasted_work():
     """Under overload, rejecting infeasible arrivals must not hurt goodput
     and every rejected request is accounted as a miss."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     st = (0.004, 0.007, 0.010)
     tm = BatchTimeModel.linear(st, (1, 2, 4, 8, 16), marginal=0.15)
     wl = Workload(n_clients=64, d_lo=0.01, d_hi=0.3, n_requests=400, seed=0)
     adm = AdmissionController(tm, mode="reject", headroom=1.0)
-    res = simulate_batched(EDF(), wl, tm, conf, correct, admission=adm)
+    res = serve_closed_loop(ServeSpec(), EDF(), wl, conf, correct,
+                            time_model=tm, admission=adm)
     n_rej = sum(1 for f in res.per_request if f.get("rejected"))
     assert n_rej == adm.rejected
     for f in res.per_request:
@@ -379,7 +388,7 @@ def test_batched_sim_admission_reduces_wasted_work():
 
 
 def test_wrapped_policy_telemetry_passthrough():
-    conf, _ = oracle_tables()
+    conf, _ = oracle_tables(600)
     tm = BatchTimeModel.linear((0.004, 0.007, 0.010), (1, 2, 4))
     base = RTDeepIoT(make_predictor("exp", prior_curve=conf.mean(0)))
     pol = as_batch_policy(base, tm)

@@ -23,7 +23,8 @@ from repro.core import RTDeepIoT, Workload, make_predictor
 from repro.models import decode_step, init_decode_cache, init_params
 from repro.serving import ServeSpec, Service
 from repro.serving.batch import BatchTimeModel
-from repro.serving.runtime import simulate_runtime
+
+from conftest import serve_closed_loop
 
 N_TOKENS = 5
 N_STAGES = 3
@@ -133,12 +134,13 @@ def test_an_open_profiler_leaves_the_virtual_clock_alone(tmp_path):
                                marginal=0.15)
 
     def run():
-        res = simulate_runtime(
+        res = serve_closed_loop(
+            ServeSpec(charge_overhead=True, pipeline_depth=2,
+                      policy_cost=2e-4),
             RTDeepIoT(make_predictor("exp", prior_curve=conf.mean(0))),
             Workload(n_clients=12, d_lo=0.01, d_hi=0.3, n_requests=120,
                      seed=0),
-            tm, conf, correct, charge_overhead=True, pipeline_depth=2,
-            policy_cost=2e-4)
+            conf, correct, time_model=tm)
         d = res.to_dict(per_request=True)
         d.pop("overhead_frac")          # the policy's own measured seconds
         for row in d["per_request"]:

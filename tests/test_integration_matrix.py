@@ -20,6 +20,8 @@ from repro.serving import Request, Service
 from repro.serving.traffic import (admission_signature, arrival_signature,
                                    scenario_spec)
 
+from conftest import oracle_tables
+
 STAGE_TIMES = (0.004, 0.007, 0.010)
 
 LLM_TIMES = (0.006, 0.010, 0.014)
@@ -30,13 +32,6 @@ ZOO = {
 }
 MIX_STAGE_TIMES = tuple(0.4 * a + 0.6 * b
                         for a, b in zip(LLM_TIMES, VISION_TIMES))
-
-
-def oracle_tables(n=200, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 def zoo_tables(models=("llm", "vision"), n=200, L=3, seed=0):
@@ -94,7 +89,7 @@ def run_scenario(scenario, overrides, trace, resources=None, stage_times=None):
     if trace:
         spec = dataclasses.replace(spec, trace={"enabled": True})
     if resources is None:
-        conf, correct = oracle_tables()
+        conf, correct = oracle_tables(200)
         resources = dict(conf_table=conf, correct_table=correct)
     return Service.from_spec(spec, **resources).run()
 
@@ -144,7 +139,7 @@ def test_zoo_model_mix_cell_is_bitwise_deterministic(trace):
 @pytest.mark.parametrize("trace", [False, True], ids=["raw", "traced"])
 def test_frontdoor_tenant_cell_is_bitwise_deterministic(trace):
     from repro.serving import ServeSpec
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
 
     def run_once():
         spec = ServeSpec(

@@ -21,7 +21,6 @@ import os
 import subprocess
 import sys
 
-import numpy as np
 import pytest
 
 from repro.core import Workload
@@ -31,14 +30,9 @@ from repro.serving.obs import (MetricsRegistry, Tracer, load_obs,
                                validate_chrome_trace)
 from repro.serving.traffic import scenario_spec
 
+from conftest import oracle_tables
+
 STAGE_TIMES = [0.004, 0.007, 0.010]
-
-
-def oracle_tables(n=600, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 def _spec(policy, trace, **kw):
@@ -56,7 +50,7 @@ def _spec(policy, trace, **kw):
 
 
 def _run(spec):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     svc = Service.from_spec(spec, conf_table=conf, correct_table=correct)
     return svc, svc.run()
 
@@ -156,7 +150,7 @@ def test_per_request_rows_emit_only_when_set():
 # ---------------------------------------------------------------------------
 
 def test_audit_covers_every_shed_request_at_2x_overload():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     for mode, rules in (("reject", {"overload", "mandatory-infeasible"}),
                         ("depth_cap", {"overload-capped",
                                        "deadline-capped",
@@ -181,7 +175,7 @@ def test_audit_covers_every_shed_request_at_2x_overload():
 
 
 def test_audit_reason_intake_bound_and_shed():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
 
     def live_spec(overflow):
         return ServeSpec(policy="edf", source="live",
@@ -214,7 +208,7 @@ def test_audit_reason_intake_bound_and_shed():
 
 def test_audit_reason_tenant_quota():
     from repro.serving.plane import FrontDoor
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     spec = ServeSpec(policy="edf", source="frontdoor",
                      batching={"stage_times": STAGE_TIMES,
                                "buckets": [1, 2, 4], "marginal": 0.15},
@@ -240,7 +234,7 @@ def test_audit_reason_tenant_quota():
 
 
 def test_audit_cancel_pullin():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     spec = ServeSpec(policy="edf", source="live",
                      batching={"stage_times": [0.05, 0.05, 0.05],
                                "buckets": [1, 2], "marginal": 0.2},
@@ -314,7 +308,7 @@ def test_validate_chrome_trace_flags_bad_docs():
 
 
 def test_planectl_trace_why_top(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     out = tmp_path / "obs.jsonl"
     spec = ServeSpec(policy="edf", source="live",
                      batching={"stage_times": STAGE_TIMES,
@@ -380,7 +374,7 @@ def test_snapshots_read_registry_counters():
                              n_requests=250,
                              admission={"mode": "reject", "headroom": 3.0},
                              metrics_interval=0.2, trace=trace)
-        conf, correct = oracle_tables()
+        conf, correct = oracle_tables(600)
         svc = Service.from_spec(spec, conf_table=conf,
                                 correct_table=correct)
         svc.run()
@@ -397,7 +391,7 @@ def test_streamer_counters_reset_on_service_reuse():
     counters are fresh per run on a reused Service, so a second run's
     metrics and first snapshot window don't inherit the first run's
     rejects."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     spec = ServeSpec(policy="edf", source="live",
                      batching={"stage_times": STAGE_TIMES,
                                "buckets": [1, 2], "marginal": 0.15},

@@ -9,7 +9,6 @@ import subprocess
 import sys
 import textwrap
 
-import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
@@ -22,15 +21,10 @@ from repro.serving.plane.frontdoor import FrontDoorSource, TokenBucket
 from repro.serving.runtime import OracleExecutor
 from repro.serving.traffic.trace import TRACE_VERSION, load_trace
 
+from conftest import oracle_tables
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STAGE_TIMES = (0.004, 0.007, 0.010)
-
-
-def oracle_tables(n=120, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 def live_spec(**overrides):
@@ -220,7 +214,7 @@ def test_scan_journal_missing_dir():
 # ---------------------------------------------------------------------------
 
 def test_durable_queue_idempotent_submission(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec()
     with Journal(str(tmp_path / "j"), spec=spec, fsync_every=1) as j:
         svc = Service.from_spec(spec, conf_table=conf, correct_table=correct)
@@ -239,7 +233,7 @@ def test_durable_queue_idempotent_submission(tmp_path):
 def test_durable_queue_replayed_duplicate_noops(tmp_path):
     """A duplicate submitted against a *reopened* journal (fresh queue,
     no in-memory handle) must not create a second SUBMIT record."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec()
     d = str(tmp_path / "j")
     with Journal(d, spec=spec, fsync_every=1) as j:
@@ -267,7 +261,7 @@ def _durable_run(journal_dir, spec, conf, correct, n=12):
 
 
 def test_recovery_reproduces_uncrashed_run_bitwise(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec()
     ref = _durable_run(str(tmp_path / "ref"), spec, conf, correct)
     crash = str(tmp_path / "crash")
@@ -288,7 +282,7 @@ def test_recovery_reproduces_uncrashed_run_bitwise(tmp_path):
 
 
 def test_recovery_spec_from_header_and_override(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec()
     d = str(tmp_path / "j")
     _durable_run(d, spec, conf, correct, n=4)
@@ -310,7 +304,7 @@ def test_recovery_spec_from_header_and_override(tmp_path):
 def test_recovery_through_frontdoor_keeps_discipline(tmp_path):
     """A frontdoor journal recovers through the same DRR arbitration the
     original run used, not a plain stream."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec(
         source="frontdoor",
         source_args={"discipline": "drr", "run_queue": 2},
@@ -382,7 +376,7 @@ def test_crash_recovery_kill9_subprocess(tmp_path):
     pre = {r.request_id for r in records if r.kind in ("RETIRE", "REJECT")}
     assert 5 <= len(pre) < 40      # genuinely mid-stream
 
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     res = recover(d, conf_table=conf, correct_table=correct)
     # exactly-once across the crash: pre-crash terminals plus the redo's
     # deliveries partition the submitted set
@@ -445,7 +439,7 @@ def test_frontdoor_drr_release_order_weighted():
 
 
 def test_frontdoor_quota_rejects_fail_fast(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec(source="frontdoor", source_args={},
                      tenants={"a": {"weight": 1.0, "rate": 10.0,
                                     "burst": 2}})
@@ -471,7 +465,7 @@ def test_drr_protects_light_tenant_fifo_starves_it():
     """The fairness claim in miniature: under ~2x overload with the
     light (low-rate, high-weight) tenant at its fair share, DRR serves
     it nearly fully while global-FIFO release order starves it."""
-    conf, correct = oracle_tables(n=400)
+    conf, correct = oracle_tables(400)
 
     def run(discipline):
         spec = live_spec(
@@ -499,7 +493,7 @@ def test_drr_protects_light_tenant_fifo_starves_it():
 
 
 def test_tenant_weight_composes_with_slo_weight():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec(
         source="frontdoor", source_args={},
         tenants={"vip": {"weight": 4.0}, "std": {"weight": 1.0}},
@@ -524,7 +518,7 @@ def test_frontdoor_validation():
         live_spec(source="frontdoor",
                   source_args={"run_queue": 0}).validate()
     with pytest.raises(ValueError, match="spec.source"):
-        conf, correct = oracle_tables()
+        conf, correct = oracle_tables(120)
         FrontDoor(Service.from_spec(live_spec(), conf_table=conf,
                                     correct_table=correct))
 
@@ -550,7 +544,7 @@ class _BoomExecutor:
 @pytest.mark.wallclock
 def test_close_survives_raising_executor_wall_clock():
     from repro.serving.batch import BatchTimeModel
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     tm = BatchTimeModel.linear(STAGE_TIMES, (1,))
     spec = live_spec(clock="wall",
                      slo_classes={"gold": {"rel_deadline": 0.5}})
@@ -569,7 +563,7 @@ def test_close_survives_raising_executor_wall_clock():
 
 def test_drain_raises_once_then_recovers_virtual():
     from repro.serving.batch import BatchTimeModel
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     tm = BatchTimeModel.linear(STAGE_TIMES, (1,))
     svc = Service.from_spec(
         live_spec(), executor=_BoomExecutor(OracleExecutor(tm, conf)),
@@ -584,7 +578,7 @@ def test_drain_raises_once_then_recovers_virtual():
 
 
 def test_drain_idempotent_after_success():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     svc = Service.from_spec(live_spec(), conf_table=conf,
                             correct_table=correct)
     svc.submit(Request(None, sample=0), at=0.0)
@@ -598,7 +592,7 @@ def test_drain_idempotent_after_success():
 # ---------------------------------------------------------------------------
 
 def test_snapshot_intake_depth_and_per_tenant():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = live_spec(
         source="frontdoor",
         source_args={"discipline": "drr", "run_queue": 1},
@@ -672,7 +666,7 @@ def test_checked_in_mini_trace_still_old_format():
 
 @pytest.mark.slow
 def test_planectl_cli(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     d = str(tmp_path / "j")
     _durable_run(d, live_spec(), conf, correct, n=6)
     truncate_after_retires(d, keep=2)

@@ -1,35 +1,29 @@
 """Tests for the unified serving runtime (repro.serving.runtime).
 
-Golden parity: the legacy entry points became EngineCore configurations;
-fixed-seed ``simulate`` / ``simulate_batched`` results (accuracy, miss
-rate, mean depth, mean confidence, makespan, throughput) must equal the
-values the pre-refactor loops produced, for RTDeepIoT, EDF, LCF and RR.
-The constants below were recorded by running the original
-``repro.core.simulator.simulate`` / ``repro.serving.batch.simulate_batched``
-implementations (PR 1 tree) on exactly this workload.
+Golden parity: fixed-seed closed-loop results on the oracle executor
+(accuracy, miss rate, mean depth, mean confidence, makespan, throughput),
+unbatched and micro-batched, must equal the values the original
+discrete-event simulators produced (PR 1 tree) on exactly this workload,
+for RTDeepIoT, EDF, LCF and RR — with the policy passed as an instance
+and with the policy built by the registry from a JSON spec.
 
-Plus: unified host-cost accounting (the legacy ``simulate_batched``
-dropped charged scheduler time), the pipelined dispatch deadline-safety
-invariant, the pipelined-vs-synchronous overhead claim on a deterministic
-cost model, and wall-clock engine smoke via the runtime.
+Plus: unified host-cost accounting on both dispatch disciplines, the
+pipelined dispatch deadline-safety invariant, the pipelined-vs-synchronous
+overhead claim on a deterministic cost model, and wall-clock engine smoke
+via the runtime.
 """
 import numpy as np
 import pytest
 
 from repro.core import (EDF, LCF, RR, RTDeepIoT, Task, Workload,
-                        make_predictor, simulate)
+                        make_predictor)
 from repro.serving import ServeSpec, Service
-from repro.serving.batch import BatchTimeModel, simulate_batched
-from repro.serving.runtime import OracleExecutor, simulate_runtime
+from repro.serving.batch import BatchTimeModel
+from repro.serving.runtime import OracleExecutor
+
+from conftest import oracle_tables, serve_closed_loop
 
 STAGE_TIMES = (0.004, 0.007, 0.010)
-
-
-def oracle_tables(n=600, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 def time_model():
@@ -46,12 +40,16 @@ def golden_workload():
     return Workload(n_clients=24, d_lo=0.01, d_hi=0.3, n_requests=300, seed=0)
 
 
+def unbatched():
+    return {"mode": "none", "stage_times": list(STAGE_TIMES)}
+
+
 # ---------------------------------------------------------------------------
-# golden parity: runtime == pre-refactor simulators, bit for bit
+# golden parity: runtime == the original simulators, bit for bit
 # ---------------------------------------------------------------------------
 
 # (accuracy, miss_rate, mean_depth, mean_conf, makespan, throughput) —
-# recorded from the pre-refactor loops at the fixed-seed workload above
+# recorded from the original loops at the fixed-seed workload above
 GOLDEN = {
     ("rtdeepiot", "sim"): (0.5, 0.0, 1.3033333333333332, 0.5391706063341832,
                            1.8434826559500153, 162.7354610751132),
@@ -79,13 +77,15 @@ GOLDEN = {
 
 @pytest.mark.parametrize("policy_name,kind", sorted(GOLDEN))
 def test_golden_parity(policy_name, kind):
-    conf, correct = oracle_tables()
+    """The policy *instance* rides into the Service as a resource."""
+    conf, correct = oracle_tables(600)
     pol = mk_policy(policy_name, conf)
     if kind == "sim":
-        res = simulate(pol, golden_workload(), STAGE_TIMES, conf, correct)
+        res = serve_closed_loop(ServeSpec(batching=unbatched()), pol,
+                                golden_workload(), conf, correct)
     else:
-        res = simulate_batched(pol, golden_workload(), time_model(), conf,
-                               correct)
+        res = serve_closed_loop(ServeSpec(), pol, golden_workload(), conf,
+                                correct, time_model=time_model())
     acc, miss, depth, mconf, makespan, thr = GOLDEN[(policy_name, kind)]
     assert res.accuracy == pytest.approx(acc, rel=1e-12)
     assert res.miss_rate == pytest.approx(miss, rel=1e-12)
@@ -98,14 +98,14 @@ def test_golden_parity(policy_name, kind):
 
 @pytest.mark.parametrize("policy_name,kind", sorted(GOLDEN))
 def test_golden_parity_via_servespec(policy_name, kind):
-    """The same pre-refactor constants, bit for bit, when the engine is
+    """The same original constants, bit for bit, when the engine is
     declared as a ServeSpec (registry-built policy included) and run
     through the Service facade — for all four policies on both
     discrete-event paths.  The spec round-trips through JSON en route."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     pargs = {"predictor": "exp"} if policy_name == "rtdeepiot" else {}
     if kind == "sim":
-        batching = {"mode": "none", "stage_times": list(STAGE_TIMES)}
+        batching = unbatched()
     else:
         batching = {"buckets": [1, 2, 4, 8, 16], "marginal": 0.15,
                     "stage_times": list(STAGE_TIMES)}
@@ -126,13 +126,15 @@ def test_golden_parity_via_servespec(policy_name, kind):
 
 
 def test_runtime_native_equals_shims():
-    """simulate_runtime(pipeline_depth=1) IS the shims' configuration."""
-    conf, correct = oracle_tables()
+    """A policy instance passed as a resource and the same policy built by
+    the registry from a JSON spec give the same schedule."""
+    conf, correct = oracle_tables(600)
     tm = time_model()
-    r1 = simulate_batched(mk_policy("edf", conf), golden_workload(), tm,
-                          conf, correct)
-    r2 = simulate_runtime(mk_policy("edf", conf), golden_workload(), tm,
-                          conf, correct)
+    r1 = serve_closed_loop(ServeSpec(), mk_policy("edf", conf),
+                           golden_workload(), conf, correct, time_model=tm)
+    spec = ServeSpec.from_json(ServeSpec(policy="edf").to_json())
+    r2 = Service.from_spec(spec, workload=golden_workload(), time_model=tm,
+                           conf_table=conf, correct_table=correct).run()
     assert r1.accuracy == r2.accuracy and r1.makespan == r2.makespan
     # identical retirement sequence (tids are a global counter — compare
     # the schedule-relevant fields instead)
@@ -142,21 +144,24 @@ def test_runtime_native_equals_shims():
 
 
 # ---------------------------------------------------------------------------
-# unified host-cost accounting (satellite: simulate_batched dropped it)
+# unified host-cost accounting
 # ---------------------------------------------------------------------------
 
 def test_charged_time_accounting_parity():
-    """With a per-dispatch overhead, BOTH discrete-event paths must report
-    the charged host time — the legacy ``simulate_batched.charge()`` threw
-    it away.  At max_batch=1 the two paths run the identical schedule, so
-    dispatch counts (and the deterministic overhead component) agree."""
-    conf, correct = oracle_tables()
+    """With a per-dispatch overhead, BOTH dispatch disciplines must report
+    the charged host time.  At max_batch=1 the unbatched and the bucketed
+    spec run the identical schedule, so dispatch counts (and the
+    deterministic overhead component) agree."""
+    conf, correct = oracle_tables(600)
     tm1 = BatchTimeModel.linear(STAGE_TIMES, (1,))
     do = 1e-3
-    r_u = simulate(mk_policy("edf", conf), golden_workload(), STAGE_TIMES,
-                   conf, correct, dispatch_overhead=do)
-    r_b = simulate_batched(mk_policy("edf", conf), golden_workload(), tm1,
-                           conf, correct, dispatch_overhead=do, max_batch=1)
+    r_u = serve_closed_loop(
+        ServeSpec(batching=unbatched(), dispatch_overhead=do),
+        mk_policy("edf", conf), golden_workload(), conf, correct)
+    r_b = serve_closed_loop(
+        ServeSpec(batching={"max_batch": 1}, dispatch_overhead=do),
+        mk_policy("edf", conf), golden_workload(), conf, correct,
+        time_model=tm1)
     assert r_u.n_dispatches == r_b.n_dispatches > 0
     # same schedule → same results
     assert r_u.accuracy == r_b.accuracy
@@ -173,13 +178,15 @@ def test_charged_time_accounting_parity():
 def test_charge_overhead_advances_virtual_time():
     """charge_overhead=True must stretch the timeline by the charged host
     time on the batched path too (it did only on the unbatched one)."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     tm = time_model()
     wl = golden_workload()
-    base = simulate_batched(mk_policy("edf", conf), wl, tm, conf, correct,
-                            dispatch_overhead=1e-3)
-    charged = simulate_batched(mk_policy("edf", conf), wl, tm, conf, correct,
-                               dispatch_overhead=1e-3, charge_overhead=True)
+    base = serve_closed_loop(ServeSpec(dispatch_overhead=1e-3),
+                             mk_policy("edf", conf), wl, conf, correct,
+                             time_model=tm)
+    charged = serve_closed_loop(
+        ServeSpec(dispatch_overhead=1e-3, charge_overhead=True),
+        mk_policy("edf", conf), wl, conf, correct, time_model=tm)
     assert charged.makespan > base.makespan
 
 
@@ -216,7 +223,7 @@ def test_pipelined_dispatch_keeps_deadline_invariant():
     deadline invariant at TRUE dispatch time, and pre-selection actually
     gets used.  The checking executor rides into the Service as a
     component-instance resource."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     tm = time_model()
     wl = Workload(n_clients=48, d_lo=0.01, d_hi=0.25, n_requests=400, seed=2)
     ex = InvariantCheckingExecutor(tm, conf)
@@ -237,17 +244,19 @@ def test_pipelined_strictly_lower_host_overhead():
     pipeline_depth=2 shows a strictly lower charged host-overhead fraction
     than synchronous batched dispatch at equal-or-better accuracy and miss
     rate, K >= 16."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     tm = time_model()
     for k in (16, 64):
         wl = Workload(n_clients=k, d_lo=0.01, d_hi=0.3, n_requests=600,
                       seed=0)
         kw = dict(charge_overhead=True, dispatch_overhead=1e-4,
                   policy_cost=5e-4)
-        r_sync = simulate_runtime(mk_policy("rtdeepiot", conf), wl, tm, conf,
-                                  correct, pipeline_depth=1, **kw)
-        r_async = simulate_runtime(mk_policy("rtdeepiot", conf), wl, tm, conf,
-                                   correct, pipeline_depth=2, **kw)
+        r_sync = serve_closed_loop(ServeSpec(pipeline_depth=1, **kw),
+                                   mk_policy("rtdeepiot", conf), wl, conf,
+                                   correct, time_model=tm)
+        r_async = serve_closed_loop(ServeSpec(pipeline_depth=2, **kw),
+                                    mk_policy("rtdeepiot", conf), wl, conf,
+                                    correct, time_model=tm)
         assert r_async.host_overhead_frac < r_sync.host_overhead_frac, k
         assert r_async.accuracy >= r_sync.accuracy, k
         assert r_async.miss_rate <= r_sync.miss_rate, k
@@ -260,13 +269,15 @@ def test_pipelined_noop_without_host_cost():
     """With zero modeled host cost the pipelined schedule cannot be worse
     than synchronous on goodput-relevant metrics (same device model; the
     only difference is when the policy looks at the queue)."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(600)
     tm = time_model()
     wl = Workload(n_clients=16, d_lo=0.02, d_hi=0.3, n_requests=300, seed=1)
-    r_s = simulate_runtime(mk_policy("edf", conf), wl, tm, conf, correct,
-                           pipeline_depth=1, policy_cost=0.0)
-    r_a = simulate_runtime(mk_policy("edf", conf), wl, tm, conf, correct,
-                           pipeline_depth=2, policy_cost=0.0)
+    r_s = serve_closed_loop(ServeSpec(pipeline_depth=1, policy_cost=0.0),
+                            mk_policy("edf", conf), wl, conf, correct,
+                            time_model=tm)
+    r_a = serve_closed_loop(ServeSpec(pipeline_depth=2, policy_cost=0.0),
+                            mk_policy("edf", conf), wl, conf, correct,
+                            time_model=tm)
     assert r_a.miss_rate <= r_s.miss_rate + 0.02
     assert r_a.accuracy >= r_s.accuracy - 0.02
 
@@ -317,7 +328,7 @@ def test_wall_clock_batched_engine_serves_all(pipelined):
 def test_engine_core_drains_unfinished_tasks_at_deadline():
     """A task the policy never schedules (infeasible) retires at its
     deadline and extends the makespan — Fig. 2 drain semantics."""
-    conf, correct = oracle_tables(n=4)
+    conf, correct = oracle_tables(4)
     tm = BatchTimeModel.linear((0.2, 0.2, 0.2), (1,))
 
     class OneShotSource:

@@ -7,8 +7,11 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import (EDF, LCF, RR, DepthPlanner, RTDeepIoT, Task,
                         Workload, brute_force_plan, greedy_update,
-                        make_predictor, simulate)
+                        make_predictor)
 from repro.core.utility import ExpIncrease, LinIncrease, MaxIncrease
+from repro.serving import ServeSpec
+
+from conftest import serve_closed_loop
 
 PRIOR = [0.5, 0.75, 0.875]
 
@@ -201,11 +204,16 @@ def _oracle(n_samples=150, L=3, seed=0):
     return conf, correct
 
 
+def unbatched_spec(times):
+    return ServeSpec(batching={"mode": "none", "stage_times": times})
+
+
 def test_simulator_no_load_no_misses():
     """With generous deadlines everything completes at full depth."""
     conf, correct = _oracle()
     wl = Workload(n_clients=2, d_lo=1.0, d_hi=2.0, n_requests=40)
-    res = simulate(EDF(), wl, [0.01] * 3, conf, correct)
+    res = serve_closed_loop(unbatched_spec([0.01] * 3), EDF(), wl, conf,
+                            correct)
     assert res.miss_rate == 0.0
     assert res.mean_depth == pytest.approx(3.0)
 
@@ -214,9 +222,11 @@ def test_rtdeepiot_beats_edf_under_overload():
     conf, correct = _oracle()
     wl = Workload(n_clients=12, d_lo=0.02, d_hi=0.15, n_requests=400)
     times = [0.02] * 3
-    r_rt = simulate(RTDeepIoT(make_predictor("exp", prior_curve=conf.mean(0))),
-                    wl, times, conf, correct)
-    r_edf = simulate(EDF(), wl, times, conf, correct)
+    r_rt = serve_closed_loop(
+        unbatched_spec(times),
+        RTDeepIoT(make_predictor("exp", prior_curve=conf.mean(0))), wl, conf,
+        correct)
+    r_edf = serve_closed_loop(unbatched_spec(times), EDF(), wl, conf, correct)
     assert r_rt.accuracy > r_edf.accuracy
     assert r_rt.miss_rate < r_edf.miss_rate
 
@@ -229,8 +239,8 @@ def test_oracle_upper_bounds_heuristics():
     for name in ("exp", "max", "lin", "oracle"):
         pred = make_predictor(name, prior_curve=conf.mean(0),
                               oracle_table=conf if name == "oracle" else None)
-        accs[name] = simulate(RTDeepIoT(pred), wl, times, conf,
-                              correct).accuracy
+        accs[name] = serve_closed_loop(unbatched_spec(times), RTDeepIoT(pred),
+                                       wl, conf, correct).accuracy
     assert accs["oracle"] >= max(accs["exp"], accs["lin"]) - 0.03
 
 
@@ -240,7 +250,8 @@ def test_policies_never_run_past_deadline_start():
     wl = Workload(n_clients=8, d_lo=0.01, d_hi=0.1, n_requests=200)
     for pol in (EDF(), LCF(), RR(),
                 RTDeepIoT(make_predictor("exp", prior_curve=conf.mean(0)))):
-        res = simulate(pol, wl, [0.02] * 3, conf, correct)
+        res = serve_closed_loop(unbatched_spec([0.02] * 3), pol, wl, conf,
+                                correct)
         for f in res.per_request:
             assert f["depth"] <= 3
 
@@ -253,8 +264,8 @@ def test_stage_counts_monotone_with_load():
     for k in (2, 20):
         wl = Workload(n_clients=k, d_lo=0.02, d_hi=0.2, n_requests=300)
         pred = make_predictor("exp", prior_curve=conf.mean(0))
-        depths.append(simulate(RTDeepIoT(pred), wl, times, conf,
-                               correct).mean_depth)
+        depths.append(serve_closed_loop(unbatched_spec(times), RTDeepIoT(pred),
+                                        wl, conf, correct).mean_depth)
     assert depths[1] <= depths[0] + 1e-9
 
 
@@ -321,7 +332,8 @@ def test_simulator_work_conserving_and_causal():
     conf, correct = _oracle(seed=11)
     wl = Workload(n_clients=10, d_lo=0.02, d_hi=0.2, n_requests=300, seed=2)
     pred = make_predictor("exp", prior_curve=conf.mean(0))
-    res = simulate(RTDeepIoT(pred), wl, [0.01, 0.02, 0.03], conf, correct)
+    res = serve_closed_loop(unbatched_spec([0.01, 0.02, 0.03]),
+                            RTDeepIoT(pred), wl, conf, correct)
     for f in res.per_request:
         assert f["deadline"] > f["arrival"]
         # a request can never execute more stages than fit in its window

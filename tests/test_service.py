@@ -3,7 +3,6 @@
 * ServeSpec round trip (dict + JSON) and validation errors
 * registry: custom policy registered and served end-to-end without
   touching core modules; component-instance resources skip the registry
-* one-shot DeprecationWarnings on all four legacy entry points
 * SLO classes, ResponseHandle futures (result / stages / cancel) in both
   virtual-buffered and wall-clock live modes
 * ServiceMetrics superset (per-class, admission counts, to_json) and
@@ -12,28 +11,21 @@
   simultaneous-arrival ordering (previously untested edges)
 """
 import json
-import warnings
 from concurrent.futures import CancelledError
 
 import numpy as np
 import pytest
 
-from repro.core import EDF, Task, Workload, simulate
+from repro.core import EDF, Task, Workload
 from repro.core.schedulers import Policy
 from repro.serving import (AdmissionController, BatchTimeModel, Request,
-                           ServeSpec, Service, simulate_batched)
-from repro.serving.deprecation import _reset as reset_deprecations
+                           ServeSpec, Service)
 from repro.serving.registry import available, register_policy, resolve
 from repro.serving.runtime.sources import StreamSource
 
+from conftest import oracle_tables, serve_closed_loop
+
 STAGE_TIMES = (0.004, 0.007, 0.010)
-
-
-def oracle_tables(n=120, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 def base_spec(**overrides):
@@ -101,7 +93,7 @@ def test_registry_custom_policy_end_to_end():
             return max(r, key=lambda t: (t.executed, -t.tid)) if r else None
 
     register_policy("test-deepest-first", lambda args, ctx: DeepestFirst())
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     wl = Workload(n_clients=6, d_lo=0.05, d_hi=0.3, n_requests=40, seed=3)
     spec = base_spec(policy="test-deepest-first")
     res = Service.from_spec(spec, workload=wl, conf_table=conf,
@@ -109,55 +101,6 @@ def test_registry_custom_policy_end_to_end():
     assert res.n_requests == 40
     assert res.miss_rate < 1.0
     assert res.components["policy"] == "test-deepest-first"
-
-
-# ---------------------------------------------------------------------------
-# one-shot deprecation warnings on the legacy entry points
-# ---------------------------------------------------------------------------
-
-def _assert_warns_exactly_once(fn):
-    with pytest.warns(DeprecationWarning, match="ServeSpec") as rec:
-        fn()
-    assert sum(issubclass(r.category, DeprecationWarning)
-               for r in rec) == 1
-    with warnings.catch_warnings():           # second call: silent
-        warnings.simplefilter("error", DeprecationWarning)
-        fn()
-
-
-def test_simulate_warns_once():
-    conf, correct = oracle_tables(n=20)
-    wl = Workload(n_clients=2, n_requests=6, seed=0)
-    reset_deprecations()
-    _assert_warns_exactly_once(
-        lambda: simulate(EDF(), wl, STAGE_TIMES, conf, correct))
-
-
-def test_simulate_batched_warns_once():
-    conf, correct = oracle_tables(n=20)
-    wl = Workload(n_clients=2, n_requests=6, seed=0)
-    tm = BatchTimeModel.linear(STAGE_TIMES, (1, 2))
-    reset_deprecations()
-    _assert_warns_exactly_once(
-        lambda: simulate_batched(EDF(), wl, tm, conf, correct))
-
-
-def test_wall_clock_engines_warn_once():
-    import jax
-    from repro.configs import get_config
-    from repro.models import init_params
-    from repro.serving import BatchedServingEngine, ServingEngine
-
-    cfg = get_config("anytime-classifier")
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    tm = BatchTimeModel.linear((0.001,) * cfg.num_stages, (1, 2))
-    eng_s = ServingEngine(cfg, params, EDF(),
-                          stage_wcet=(0.001,) * cfg.num_stages)
-    eng_b = BatchedServingEngine(cfg, params, EDF(), time_model=tm)
-    reset_deprecations()
-    # an empty stream exercises the deprecation path without serving work
-    _assert_warns_exactly_once(lambda: eng_s.run([]))
-    _assert_warns_exactly_once(lambda: eng_b.run([]))
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +116,7 @@ SLO_SPEC = dict(
 
 
 def test_slo_classes_and_futures_virtual():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     svc = Service.from_spec(ServeSpec(**SLO_SPEC), conf_table=conf,
                             correct_table=correct)
     h_gold = svc.submit(Request(None, sample=3), at=0.0)
@@ -199,7 +142,7 @@ def test_slo_classes_and_futures_virtual():
 
 
 def test_submit_unknown_slo_rejected_and_cancel():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     svc = Service.from_spec(ServeSpec(**SLO_SPEC), conf_table=conf,
                             correct_table=correct)
     with pytest.raises(KeyError, match="unknown SLO class"):
@@ -220,7 +163,7 @@ def test_submit_unknown_slo_rejected_and_cancel():
 
 
 def test_run_refuses_live_source_and_submit_refuses_batch_source():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     svc = Service.from_spec(ServeSpec(**SLO_SPEC), conf_table=conf,
                             correct_table=correct)
     with pytest.raises(RuntimeError, match="submit"):
@@ -237,7 +180,7 @@ def test_run_refuses_live_source_and_submit_refuses_batch_source():
 
 @pytest.mark.wallclock
 def test_live_wall_clock_service_serves_submissions():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = ServeSpec(
         policy="edf", executor="oracle", clock="wall", source="live",
         batching={"mode": "none", "stage_times": [0.002, 0.002, 0.002]},
@@ -266,7 +209,7 @@ def test_live_engine_failure_fans_out_to_handles():
         def submit(self, stage, tasks, now):
             raise RuntimeError("boom")
 
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     tm = BatchTimeModel.linear(STAGE_TIMES, (1,))
     spec = ServeSpec(
         policy="edf", executor="oracle", clock="wall", source="live",
@@ -282,7 +225,7 @@ def test_live_engine_failure_fans_out_to_handles():
 
 
 def test_submit_without_any_deadline_fails_fast():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = ServeSpec(
         policy="edf", executor="oracle", clock="virtual", source="live",
         batching={"mode": "none", "stage_times": list(STAGE_TIMES)})
@@ -296,7 +239,7 @@ def test_submit_without_any_deadline_fails_fast():
 # ---------------------------------------------------------------------------
 
 def test_service_metrics_superset_and_json():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     wl = Workload(n_clients=16, d_lo=0.01, d_hi=0.1, n_requests=60, seed=1)
     spec = base_spec(
         batching={"buckets": [1, 2, 4], "stage_times": list(STAGE_TIMES)},
@@ -315,9 +258,9 @@ def test_service_metrics_superset_and_json():
 
 
 def test_simresult_to_dict():
-    conf, correct = oracle_tables(n=20)
+    conf, correct = oracle_tables(20)
     wl = Workload(n_clients=2, n_requests=6, seed=0)
-    res = simulate(EDF(), wl, STAGE_TIMES, conf, correct)
+    res = serve_closed_loop(base_spec(), EDF(), wl, conf, correct)
     d = res.to_dict()
     assert d["accuracy"] == res.accuracy and "per_request" not in d
     assert set(d) >= {"miss_rate", "makespan", "throughput", "sched_charged"}
@@ -410,7 +353,7 @@ def test_stream_source_zero_slack_request_is_counted_as_miss():
     §II-B adjustment) must still flow through admit -> expire -> retire as
     a depth-0 miss, not be dropped silently; simultaneous arrivals keep
     insertion order in the task ids."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(120)
     spec = base_spec(source="stream")
     reqs = [(0.0, Request(None, 0.0, sample=1)),       # zero slack: miss
             (0.0, Request(None, 0.5, sample=2)),

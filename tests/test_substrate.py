@@ -157,20 +157,21 @@ def test_train_decreases_loss(rng):
 @pytest.mark.slow                    # jax compile dominates; no 20x repeat
 @pytest.mark.wallclock
 def test_serving_engine_end_to_end(rng):
-    from repro.serving import (ServeSpec, Service, closed_loop_stream,
-                               make_stage_fns, profile_stages)
+    from repro.serving import (BatchedStageFns, ServeSpec, Service,
+                               closed_loop_stream, profile_batched_stages)
 
     cfg = get_config("anytime-classifier")
     params = init_params(cfg, rng)
     ds = DifficultyDataset(num_classes=cfg.vocab_size, seed=0)
     test = ds.sample(40, seed=9)
-    fns = make_stage_fns(cfg)
+    fns = BatchedStageFns(cfg, (1,))
     sample = jax.tree.map(lambda x: x[:1], test["inputs"])
-    wcet, _, _ = profile_stages(cfg, params, fns, sample, n_runs=5)
+    _, mat = profile_batched_stages(cfg, params, fns, sample, n_runs=5)
+    wcet = mat[:, 0]
     spec = ServeSpec(policy="rtdeepiot",
                      policy_args={"predictor": "exp",
                                   "prior_curve": [.5, .7, .85]},
-                     executor="device-single", clock="wall", source="stream",
+                     executor="device-batched", clock="wall", source="stream",
                      batching={"mode": "none",
                                "stage_times": [float(x) for x in wcet]})
     svc = Service.from_spec(spec, cfg=cfg, params=params, stage_fns=fns)
@@ -193,20 +194,21 @@ def test_serving_engine_end_to_end(rng):
 @pytest.mark.slow                    # jax compile dominates; no 20x repeat
 @pytest.mark.wallclock
 def test_serving_engine_tight_deadlines_shed_stages(rng):
-    from repro.serving import (ServeSpec, Service, closed_loop_stream,
-                               make_stage_fns, profile_stages)
+    from repro.serving import (BatchedStageFns, ServeSpec, Service,
+                               closed_loop_stream, profile_batched_stages)
 
     cfg = get_config("anytime-classifier")
     params = init_params(cfg, rng)
     ds = DifficultyDataset(num_classes=cfg.vocab_size, seed=0)
     test = ds.sample(40, seed=9)
-    fns = make_stage_fns(cfg)
+    fns = BatchedStageFns(cfg, (1,))
     sample = jax.tree.map(lambda x: x[:1], test["inputs"])
-    wcet, _, _ = profile_stages(cfg, params, fns, sample, n_runs=5)
+    _, mat = profile_batched_stages(cfg, params, fns, sample, n_runs=5)
+    wcet = mat[:, 0]
     spec = ServeSpec(policy="rtdeepiot",
                      policy_args={"predictor": "exp",
                                   "prior_curve": [.5, .7, .85]},
-                     executor="device-single", clock="wall", source="stream",
+                     executor="device-batched", clock="wall", source="stream",
                      batching={"mode": "none",
                                "stage_times": [float(x) for x in wcet]})
     svc = Service.from_spec(spec, cfg=cfg, params=params, stage_fns=fns)
@@ -216,3 +218,49 @@ def test_serving_engine_tight_deadlines_shed_stages(rng):
     svc.run(stream)
     depths = [r.depth for r in svc.responses if not r.missed]
     assert depths and np.mean(depths) < cfg.num_stages  # shedding happened
+
+
+def test_unbatched_device_matches_direct_stage_calls(rng):
+    """``device-batched`` under ``batching={"mode": "none"}`` dispatches
+    singleton batches whose exits equal direct jitted ``stage_forward``
+    calls, stage by stage (a depth cap per request picks the exit)."""
+    from repro.models import stage_forward
+    from repro.serving import BatchedStageFns, Request, ServeSpec, Service
+
+    cfg = get_config("anytime-classifier")
+    params = init_params(cfg, rng)
+    L = cfg.num_stages
+    test = DifficultyDataset(num_classes=cfg.vocab_size,
+                             seed=0).sample(2 * L, seed=9)
+    sizes = []
+
+    class CountingStageFns(BatchedStageFns):
+        def run(self, stage, params, pytrees):
+            sizes.append(len(pytrees))
+            return super().run(stage, params, pytrees)
+
+    def inputs(i):
+        return jax.tree.map(lambda x: x[i:i + 1], test["inputs"])
+
+    spec = ServeSpec(policy="edf", executor="device-batched",
+                     clock="virtual", source="stream",
+                     batching={"mode": "none", "stage_times": [0.001] * L},
+                     slo_classes={f"d{d}": {"rel_deadline": 1.0,
+                                            "depth_cap": d}
+                                  for d in range(1, L + 1)})
+    svc = Service.from_spec(spec, cfg=cfg, params=params,
+                            stage_fns=CountingStageFns(cfg, (1,)))
+    svc.run([(0.0, Request(inputs(i), sample=i, slo=f"d{i % L + 1}"))
+             for i in range(2 * L)])
+    assert sizes and set(sizes) == {1}
+    assert len(svc.responses) == 2 * L
+    direct = [jax.jit(lambda p, h, _s=s: stage_forward(cfg, p, _s, h,
+                                                       mode="train"))
+              for s in range(L)]
+    for r in svc.responses:
+        assert not r.missed and r.depth == r.sample % L + 1
+        h = inputs(r.sample)
+        for s in range(r.depth):
+            h, logits, conf = direct[s](params, h)
+        assert r.prediction == int(np.argmax(np.asarray(logits)[0]))
+        assert r.confidence == float(np.max(np.asarray(conf)[0]))

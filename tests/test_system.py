@@ -7,11 +7,13 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import (EDF, LCF, RR, RTDeepIoT, Workload, make_predictor,
-                        simulate)
+from repro.core import EDF, LCF, RR, RTDeepIoT, Workload, make_predictor
 from repro.models import init_params
+from repro.serving import ServeSpec
 from repro.training import (AdamW, DifficultyDataset, eval_exit_metrics,
                             make_train_step, warmup_cosine)
+
+from conftest import serve_closed_loop
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +72,8 @@ def test_rtdeepiot_dominates_baselines_on_trained_tables(trained):
                                                prior_curve=conf.mean(0)))),
         ("edf", EDF()), ("lcf", LCF()), ("rr", RR()),
     ]:
-        accs[name] = simulate(pol, wl, times, conf, correct).accuracy
+        spec = ServeSpec(batching={"mode": "none", "stage_times": times})
+        accs[name] = serve_closed_loop(spec, pol, wl, conf, correct).accuracy
     assert accs["rtdeepiot"] >= max(accs["edf"], accs["lcf"],
                                     accs["rr"]) - 1e-9
 

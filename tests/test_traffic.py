@@ -29,14 +29,9 @@ from repro.serving.traffic import (ARRIVAL_KINDS, SCENARIOS, RequestMix,
                                    TraceRecorder, TrafficSource, load_trace,
                                    make_arrival_process, nominal_rate)
 
+from conftest import oracle_tables
+
 STAGE_TIMES = (0.004, 0.007, 0.010)
-
-
-def oracle_tables(n=200, L=3, seed=0):
-    rng = np.random.default_rng(seed)
-    conf = np.sort(rng.uniform(0.3, 1.0, (n, L)), axis=1)
-    correct = rng.uniform(size=(n, L)) < conf
-    return conf, correct.astype(bool)
 
 
 # ---------------------------------------------------------------------------
@@ -136,7 +131,7 @@ def test_traffic_source_is_open_loop():
 
 
 def test_traffic_scenario_through_service():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("steady", policy="edf", stage_times=STAGE_TIMES,
                          n_requests=60, seed=2)
     assert ServeSpec.from_json(spec.to_json()) == spec    # JSON round trip
@@ -152,7 +147,7 @@ def test_traffic_scenario_through_service():
 
 
 def test_traffic_source_requires_sizing_args():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("steady", stage_times=STAGE_TIMES)
     spec.source_args.pop("n_requests")
     with pytest.raises(ValueError, match="n_requests"):
@@ -187,7 +182,7 @@ def _replay_of(spec, metrics, conf, correct, tmp_path):
 
 
 def test_replay_reproduces_overloaded_run_bitwise(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("flash-crowd", policy="rtdeepiot",
                          admission={"mode": "reject"},
                          stage_times=STAGE_TIMES, n_requests=150, seed=3)
@@ -207,7 +202,7 @@ def test_replay_reproduces_overloaded_run_bitwise(tmp_path):
 
 
 def test_trace_jsonl_schema(tmp_path):
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("steady", policy="edf", stage_times=STAGE_TIMES,
                          n_requests=12, seed=0)
     res = Service.from_spec(spec, conf_table=conf,
@@ -226,7 +221,7 @@ def test_trace_jsonl_schema(tmp_path):
 
 
 def test_replay_source_needs_a_trace():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = scenario_spec("steady", stage_times=STAGE_TIMES, n_requests=5)
     spec = dataclasses.replace(spec, source="replay", source_args={})
     with pytest.raises(KeyError, match="trace"):
@@ -238,7 +233,7 @@ def test_trace_capture_of_closed_loop_run_replays_load_shape(tmp_path):
     replay is not bit-exact (the factory re-adjusts), but every arrival
     must survive the round trip in order."""
     from repro.core import Workload
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = ServeSpec(policy="edf", executor="oracle", clock="virtual",
                      source="closed-loop",
                      batching={"mode": "none",
@@ -271,7 +266,7 @@ def live_spec(**source_args):
 
 
 def test_backpressure_reject_fails_fast():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     svc = Service.from_spec(live_spec(bound=2, overflow="reject"),
                             conf_table=conf, correct_table=correct)
     handles = [svc.submit(Request(None, sample=i), at=0.0) for i in range(5)]
@@ -289,7 +284,7 @@ def test_backpressure_reject_fails_fast():
 
 
 def test_backpressure_shed_optional_drops_depth_not_requests():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     svc = Service.from_spec(live_spec(bound=1, overflow="shed-optional"),
                             conf_table=conf, correct_table=correct)
     h1 = svc.submit(Request(None, sample=1), at=0.0)
@@ -308,7 +303,7 @@ def test_shed_pin_survives_admission_depth_cap():
     """Admission control must only ever *tighten* an existing depth cap:
     a shed-optional request pinned to mandatory stays at mandatory even
     when admission's own solo-feasibility cap would allow deeper."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = dataclasses.replace(live_spec(bound=1, overflow="shed-optional"),
                                admission={"mode": "depth_cap"},
                                slo_classes={"gold": {"rel_deadline": 0.035}})
@@ -325,7 +320,7 @@ def test_shed_pin_survives_admission_depth_cap():
 def test_slo_depth_cap_survives_admission_depth_cap():
     """Same invariant for SLO-class caps: bronze pinned to depth 1 must
     not be re-opened by admission's deadline-capped decision."""
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = ServeSpec(
         policy="edf", executor="oracle", clock="virtual", source="live",
         batching={"mode": "none", "stage_times": list(STAGE_TIMES)},
@@ -352,7 +347,7 @@ def test_backpressure_spec_validation():
 # ---------------------------------------------------------------------------
 
 def test_metrics_streaming_captures_flash_crowd_transient():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     snaps = []
     spec = scenario_spec("flash-crowd", policy="edf",
                          stage_times=STAGE_TIMES, n_requests=150, seed=1,
@@ -389,7 +384,7 @@ def test_streaming_requires_positive_interval():
 
 @pytest.mark.wallclock
 def test_cancel_after_admission_sheds_optional_stages():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     spec = ServeSpec(
         policy="edf", executor="oracle", clock="wall", source="live",
         batching={"mode": "none", "stage_times": [0.04, 0.04, 0.04]},
@@ -416,7 +411,7 @@ def test_cancel_after_admission_sheds_optional_stages():
 # ---------------------------------------------------------------------------
 
 def test_weighted_policy_favors_gold_under_overload():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     rate = 2.0 * nominal_rate(STAGE_TIMES)
     spec = ServeSpec(
         policy="rtdeepiot-weighted",
@@ -454,7 +449,7 @@ def _run_stream(reqs, conf, correct):
 
 
 def test_stream_source_shuffled_offsets_match_sorted():
-    conf, correct = oracle_tables()
+    conf, correct = oracle_tables(200)
     rng = np.random.default_rng(0)
     offs = np.cumsum(rng.uniform(0.001, 0.02, 40))
     reqs = [(float(t), Request(None, 0.15, sample=i))
