@@ -19,8 +19,9 @@ the registry's extension points (no core module touched):
   host's read-and-decide overlaps device compute — a speculatively
   dispatched depth is discarded when the target was already met;
 * source ``token-loop`` — a closed loop of one token at a time: retiring
-  token *t* commits the chosen depth's cache, samples token *t+1* and
-  issues it as the next request;
+  token *t* commits the chosen depth's slots (the executor writes them
+  into its caches in place), samples token *t+1* and issues it as the
+  next request;
 * executor ``device-sharded`` (:mod:`repro.launch.sharded`) — the batched
   classifier engine with its stage fns sharded over a ``(dp, tp)`` mesh
   from :func:`repro.launch.mesh.make_serving_mesh`; a mesh larger than
@@ -46,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import math
 import time
 
@@ -93,20 +95,40 @@ def _make_conf_target(args, ctx):
     return _ConfTarget(float(args.get("target", 0.7)))
 
 
+@functools.cache
+def _slot_writer():
+    """``write_decode_slots`` jitted once per process with the stage
+    caches donated: one program per depth written, each in place."""
+    import jax
+
+    from repro.models import write_decode_slots
+    return jax.jit(write_decode_slots, donate_argnums=0)
+
+
 class DecodeExecutor:
     """Jitted per-depth decode steps behind the runtime Executor contract.
 
     Depth *d*'s "stage" takes the token to depth d+1.  Depth 1 runs from
-    the embedding and the committed cache; depth d+1 resumes from depth
-    d: its step gets depth d's ``h_final`` as a
-    :class:`~repro.models.DecodeFrom` and depth d's new cache, and runs
-    only stage d+1 and its exit.  The other stages' exits and caches are
-    carried over from depth d on the host, so each output still holds one
-    exit per stage run and a whole cache.  With ``speculate`` the next-deeper step is dispatched
-    asynchronously before the current depth's confidence readback blocks.
-    Each step's confidence starts its copy to the host as soon as the step
-    is dispatched, and ``commit`` averages it on the host: the readback
+    the embedding; depth d+1 resumes from depth d: its step gets depth
+    d's ``h_final`` as a :class:`~repro.models.DecodeFrom` and runs only
+    stage d+1 and its exit.  Every step reads the committed stage caches,
+    each passed as a :class:`~repro.models.CommittedCache`, and returns
+    only the token's slots of the stages it runs; the exits and slots of
+    the depths before are carried over on the host, so each output holds
+    one exit per stage run and the slots of stages 1..d.  With
+    ``speculate`` the next-deeper step is dispatched asynchronously before
+    the current depth's confidence readback blocks, and a discarded
+    speculative depth leaves the committed caches as they were.  Each
+    step's confidence starts its copy to the host as soon as the step is
+    dispatched, and ``commit`` averages it on the host: the readback
     enqueues no device work, so it never waits behind a speculative step.
+
+    The executor owns ``cache``, the committed stage caches: the retired
+    token's slots (:meth:`commit_slots`) are written into them in place,
+    with the caches donated, before the next token's depth-1 step or the
+    next read of ``cache``.  So the arrays it was given, and any that
+    ``cache`` returned, are consumed by the next write.  Assigning
+    ``cache`` installs a committed state and drops a write not yet made.
     """
 
     def __init__(self, steps, params, cache, tok, *, speculate=False):
@@ -116,8 +138,9 @@ class DecodeExecutor:
         import jax
         import jax.numpy as jnp
 
-        from repro.models import DecodeFrom
-        self._jax, self._jnp, self._from = jax, jnp, DecodeFrom
+        from repro.models import CommittedCache, DecodeFrom
+        self._jax, self._jnp = jax, jnp
+        self._from, self._committed = DecodeFrom, CommittedCache
         self.steps = steps
         self.params = params
         self.cache = cache
@@ -127,13 +150,50 @@ class DecodeExecutor:
         self.speculated = 0          # deeper steps dispatched speculatively
         self.spec_hits = 0           # ... that the schedule then consumed
         self.chained = 0             # steps resumed from the depth before
+        self.slot_writes = 0         # stage slots written into `cache`
         self.readbacks = 0           # confidences read back in `commit`
         self.readbacks_overlapped = 0  # ... with a deeper step in flight
         self._running = None
-        self._spec = None            # (token, stage, out, new_cache)
+        self._spec = None            # (token, stage, out, slots)
         self._done = None
         self._done_at = None         # (token, stage) of `_done`
-        self.chosen = None           # (out, new_cache) of the last commit
+        self.chosen = None           # (out, slots) of the last commit
+
+    @property
+    def cache(self):
+        """The committed stage caches, the last retired token's slots
+        written in."""
+        self._write_slots()
+        return self._cache
+
+    @cache.setter
+    def cache(self, value):
+        # placed like the caches the write returns (no copy), so that one
+        # program per depth serves every write and step
+        jax = self._jax
+        self._install(jax.tree.map(
+            lambda x: x if x.committed else jax.device_put(x, x.sharding),
+            list(value)))
+
+    def _install(self, stages):
+        self._cache, self._slots = stages, None
+        self._view = [self._committed(c) for c in stages]
+
+    def commit_slots(self, slots):
+        """Commit a retired token's slots, ``chosen``'s second half: the
+        stages its depth ran, None for the deeper ones, which keep their
+        committed caches as they are."""
+        self._slots = slots
+
+    def _write_slots(self):
+        slots, self._slots = self._slots, None
+        if slots is None:
+            return
+        d = sum(s is not None for s in slots)
+        with span("repro.executor.write_slots", stages=d):
+            written = _slot_writer()(self._cache[:d], slots[:d])
+        self._install([*written, *self._cache[d:]])
+        self.slot_writes += d
 
     # -- Executor contract ---------------------------------------------
     @property
@@ -144,24 +204,23 @@ class DecodeExecutor:
         return 0.0
 
     def _dispatch(self, stage, pos, prev=None):
-        """Enqueue depth ``stage + 1``: from the token and the committed
-        cache, or resumed from ``prev``, depth ``stage``'s
-        ``(out, new_cache)``, whose exits and stage caches fill in what
-        the resumed step does not return."""
+        """Enqueue depth ``stage + 1`` on the committed caches: from the
+        token, or resumed from ``prev``, depth ``stage``'s ``(out,
+        slots)``, whose exits and slots come first in the result."""
         if prev is None:
-            out, new_cache = self.steps[stage](self.params, self.cache,
-                                               self.tok, pos)
+            out, slots = self.steps[stage](self.params, self._view,
+                                           self.tok, pos)
             out.confidences[-1].copy_to_host_async()
-            return out, new_cache
-        head, cache = prev
-        out, new_cache = self.steps[stage](
-            self.params, cache, self._from(head.h_final, stage), pos)
+            return out, slots
+        head, slots = prev
+        out, new = self.steps[stage](
+            self.params, self._view, self._from(head.h_final, stage), pos)
         out.confidences[-1].copy_to_host_async()
         self.chained += 1
         out = dataclasses.replace(
             out, logits=[*head.logits, *out.logits],
             confidences=[*head.confidences, *out.confidences])
-        return out, [c if n is None else n for c, n in zip(cache, new_cache)]
+        return out, [s if n is None else n for s, n in zip(slots, new)]
 
     def submit(self, stage, tasks, now):
         jnp = self._jnp
@@ -172,31 +231,32 @@ class DecodeExecutor:
         ahead = self.speculate and stage + 1 < len(self.steps)
         with span("repro.executor.launch", depth=stage + 1, hit=hit,
                   chained=resume + ahead):
+            if stage == 0:
+                self._write_slots()
             pos = jnp.full((self.tok.shape[0],), task.sample, jnp.int32)
             if hit:
-                out, new_cache = self._spec[2:]
+                out, slots = self._spec[2:]
                 self.spec_hits += 1
             else:
-                out, new_cache = self._dispatch(
+                out, slots = self._dispatch(
                     stage, pos, self._done if resume else None)
             self._spec = None
             if ahead:
                 self._spec = (task.sample, stage + 1,
-                              *self._dispatch(stage + 1, pos,
-                                              (out, new_cache)))
+                              *self._dispatch(stage + 1, pos, (out, slots)))
                 self.speculated += 1
-        self._running = (stage, tasks, out, new_cache, now)
+        self._running = (stage, tasks, out, slots, now)
 
     def finish_time(self):
         return None if self.busy else math.inf
 
     def complete(self, clock):
-        stage, tasks, out, new_cache, t0 = self._running
+        stage, tasks, out, slots, t0 = self._running
         self._running = None
         with span("repro.executor.wait"):
             self._jax.block_until_ready(out.logits[-1])
         self.total_busy += clock.now() - t0
-        self._done = (out, new_cache)
+        self._done = (out, slots)
         self._done_at = (tasks[0].sample, stage)
         return stage, tasks
 
@@ -263,8 +323,8 @@ def _make_device_kernel(args, ctx):
 
 class TokenLoopSource:
     """Closed loop of one token request at a time: retiring token *t*
-    commits the chosen depth's cache, lets the ``advance`` callback sample
-    token *t+1*, and issues it as the next request."""
+    commits the chosen depth's slots to the executor, lets the ``advance``
+    callback sample token *t+1*, and issues it as the next request."""
 
     def __init__(self, n_tokens, n_stages, executor, advance):
         self.n_tokens = n_tokens
@@ -290,8 +350,8 @@ class TokenLoopSource:
 
     def on_retire(self, task, now):
         with span("repro.source.advance"):
-            out, new_cache = self.executor.chosen
-            self.executor.cache = new_cache
+            out, slots = self.executor.chosen
+            self.executor.commit_slots(slots)
             self.executor.tok = self.advance(task, out)
         self._next += 1
         if self._next < self.n_tokens:
@@ -359,8 +419,8 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from repro.launch.compile_cache import enable_compile_cache
-    from repro.models import (DecodeFrom, decode_step, init_decode_cache,
-                              init_params)
+    from repro.models import (CommittedCache, DecodeFrom, decode_step,
+                              init_decode_cache, init_params)
     from repro.training import checkpoint
 
     enable_compile_cache()
@@ -369,7 +429,10 @@ def main(argv=None):
         params, _ = checkpoint.load(args.ckpt, params)
     params_gb = sum(x.nbytes for x in jax.tree.leaves(params)) / 1e9
     B = args.batch
-    cache = init_decode_cache(cfg, B, slots=args.tokens + 1)
+    # committed to the device, as the executor holds it: a jitted program
+    # is compiled apart for committed and uncommitted arguments
+    cache = jax.device_put(init_decode_cache(cfg, B, slots=args.tokens + 1),
+                           jax.devices()[0])
 
     # jit one step per depth (the per-stage dispatch units of the engine)
     steps = [jax.jit(lambda p, c, t, pos, _d=d: decode_step(
@@ -378,14 +441,15 @@ def main(argv=None):
     tok = (jnp.zeros((B, cfg.num_codebooks), jnp.int32)
            if cfg.modality == "audio_stub" else jnp.zeros((B,), jnp.int32))
     # compile what the executor runs before the clock starts: depth 1 from
-    # the token, each deeper depth resumed from the one before (first call
-    # = compile + one step; the persistent cache turns a rerun's compile
-    # into a load)
+    # the token, each deeper depth resumed from the one before, all on the
+    # committed caches (first call = compile + one step; the persistent
+    # cache turns a rerun's compile into a load)
     t0 = time.perf_counter()
     pos0 = jnp.zeros((B,), jnp.int32)
-    out, _ = steps[0](params, cache, tok, pos0)
+    view = [CommittedCache(c) for c in cache]
+    out, _ = steps[0](params, view, tok, pos0)
     for d in range(1, n_stages):
-        out, _ = steps[d](params, cache, DecodeFrom(out.h_final, d), pos0)
+        out, _ = steps[d](params, view, DecodeFrom(out.h_final, d), pos0)
     jax.block_until_ready(out.logits[-1])
     del out
     compile_s = time.perf_counter() - t0

@@ -18,9 +18,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import (KeyGen, apply_rope, dense_init,
-                                 param_dtype, rms_norm, rms_norm_head, shard,
-                                 shard_residual)
+from repro.models.common import (CommittedCache, KeyGen, apply_rope,
+                                 dense_init, param_dtype, rms_norm,
+                                 rms_norm_head, shard, shard_residual)
 
 NEG_INF = -1e30
 
@@ -137,6 +137,37 @@ def decode_attend(q, k_cache, v_cache, k_pos, cur_pos, *, window, softmax_scale)
     return jnp.einsum("bkgs,bskh->bkgh", p, v_cache)
 
 
+def decode_attend_committed(scores, score_new, v_cache, v_new, k_pos,
+                            write_idx, cur_pos, *, window, dtype):
+    """:func:`decode_attend` over a cache the step does not write.
+
+    The keys are the cache's slots, less the slot the token would
+    overwrite (``write_idx``: on a ring cache it holds a stale position),
+    and the token's own key, with one softmax over both.  scores: (B, KV,
+    G, S) and score_new: (B, KV, G), scaled, float32; v_cache: (B, S, KV,
+    hd); v_new: (B, KV, hd); k_pos: (S,) positions held by the slots.
+    """
+    S = scores.shape[-1]
+    valid = (k_pos[None] <= cur_pos[:, None]) & (k_pos[None] >= 0)
+    if window is not None:
+        valid &= cur_pos[:, None] - k_pos[None] < window
+    valid &= jnp.arange(S)[None] != write_idx[:, None]
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    p = jax.nn.softmax(jnp.concatenate([scores, score_new[..., None]], -1),
+                       axis=-1).astype(dtype)
+    out = jnp.einsum("bkgs,bskh->bkgh", p[..., :S], v_cache,
+                     preferred_element_type=jnp.float32)
+    out += p[..., S:].astype(jnp.float32) * v_new[:, :, None].astype(
+        jnp.float32)
+    return out.astype(dtype)
+
+
+def _committed_route(ctx):
+    if ctx is not None and ctx.decode_attn in ("kernel", "flash_decode"):
+        raise ValueError(f"decode_attn={ctx.decode_attn!r} runs on a plain "
+                         "cache, not a CommittedCache")
+
+
 # ---------------------------------------------------------------------------
 # GQA block
 # ---------------------------------------------------------------------------
@@ -230,7 +261,9 @@ def apply_gqa_step(cfg, params, x, *, cache, cur_pos, local: bool, ctx):
     as many slots as max positions (write slot = position); a *ring* cache
     (sliding-window layers / swa-8192 long-context variant) has `window`
     slots and wraps — `slot_pos` records which position each slot holds so
-    masking stays exact either way.
+    masking stays exact either way.  A :class:`CommittedCache` is read and
+    not written: the token's slot (``k``, ``v``, ``slot_pos``, ``write_idx``)
+    comes back in place of a new cache.
     """
     from repro.models import flash_decode
 
@@ -244,6 +277,24 @@ def apply_gqa_step(cfg, params, x, *, cache, cur_pos, local: bool, ctx):
                            allow_flat=False)
     q = q[:, 0]                      # (B,KV,G,hd)
     k_new, v_new = k[:, 0], v[:, 0]  # (B,KV,hd)
+    window = cfg.sliding_window if local else None
+    scale = hd ** -0.5
+    if isinstance(cache, CommittedCache):
+        _committed_route(ctx)
+        c = cache.cache
+        write_idx = cur_pos % c["k"].shape[1]
+        k_new = k_new.astype(c["k"].dtype)
+        v_new = v_new.astype(c["v"].dtype)
+        scores = jnp.einsum("bkgh,bskh->bkgs", q, c["k"]).astype(
+            jnp.float32) * scale
+        score_new = jnp.einsum("bkgh,bkh->bkg", q, k_new).astype(
+            jnp.float32) * scale
+        out = decode_attend_committed(
+            scores, score_new, c["v"], v_new, c["slot_pos"][0], write_idx,
+            cur_pos, window=window, dtype=q.dtype)
+        y = out.reshape(B, H * hd) @ params["wo"]
+        return x + y, {"k": k_new, "v": v_new, "slot_pos": cur_pos,
+                       "write_idx": write_idx}
 
     n_slots = cache["k"].shape[1]
     write_idx = cur_pos % n_slots    # (B,) slot to overwrite (ring-aware)
@@ -252,8 +303,6 @@ def apply_gqa_step(cfg, params, x, *, cache, cur_pos, local: bool, ctx):
     v_cache = cache["v"].at[bidx, write_idx].set(v_new.astype(cache["v"].dtype))
     slot_pos = cache["slot_pos"].at[bidx, write_idx].set(cur_pos)
 
-    window = cfg.sliding_window if local else None
-    scale = hd ** -0.5
     if ctx is not None and ctx.decode_attn == "kernel":
         # Pallas decode kernel over the (ring) cache: per-row slot_pos
         # masking makes ragged co-batched requests exact.  Head axis is
@@ -357,7 +406,9 @@ def apply_mla_step(cfg, params, x, *, cache, cur_pos, ctx, **_):
     (kv_lora + rope) latent space against the compressed cache — structurally
     MQA with a single shared 576-dim "kv head".  The attention output (a
     weighted sum of latents) is then expanded once per head through wkv_b's
-    value half.
+    value half.  A :class:`CommittedCache` is read and not written: the
+    token's slot (``latent``, ``k_rope``, ``slot_pos``, ``write_idx``) comes
+    back in place of a new cache.
     """
     from repro.models import flash_decode
 
@@ -370,6 +421,42 @@ def apply_mla_step(cfg, params, x, *, cache, cur_pos, ctx, **_):
         cfg, params, h3, cur_pos[:, None])
     q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]              # (B,H,*)
 
+    # absorb q through the key half of wkv_b: (B,H,nope) -> (B,H,kv_lora)
+    wkv_b = params["wkv_b"].reshape(
+        m.kv_lora_rank, H, m.qk_nope_head_dim + m.v_head_dim)
+    w_bk = wkv_b[:, :, :m.qk_nope_head_dim]                  # (lora,H,nope)
+    w_bv = wkv_b[:, :, m.qk_nope_head_dim:]                  # (lora,H,v)
+    q_lat = jnp.einsum("bhn,lhn->bhl", q_nope, w_bk)         # (B,H,lora)
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+
+    def expand(out_lat):                                     # to v-space
+        out = jnp.einsum("bhl,lhv->bhv", out_lat.reshape(B, H, -1), w_bv)
+        return x + out.reshape(B, H * m.v_head_dim) @ params["wo"]
+
+    if isinstance(cache, CommittedCache):
+        _committed_route(ctx)
+        c = cache.cache
+        write_idx = cur_pos % c["latent"].shape[1]
+        lat_new = latent_new[:, 0].astype(c["latent"].dtype)
+        kr_new = k_rope_new[:, 0].astype(c["k_rope"].dtype)
+
+        def score(lat, kr, spec):
+            # the latent and rope halves of one (lora + rope)-wide dot
+            f32 = jnp.float32
+            s = (jnp.einsum(spec.format("l"), q_lat, lat,
+                            preferred_element_type=f32)
+                 + jnp.einsum(spec.format("r"), q_rope, kr,
+                              preferred_element_type=f32))
+            return s.astype(q_lat.dtype).astype(f32)[:, None] * scale
+
+        out = decode_attend_committed(
+            score(c["latent"], c["k_rope"], "bh{0},bs{0}->bhs"),
+            score(lat_new, kr_new, "bh{0},b{0}->bh"),
+            c["latent"][:, :, None], lat_new[:, None], c["slot_pos"][0],
+            write_idx, cur_pos, window=None, dtype=q_lat.dtype)
+        return expand(out), {"latent": lat_new, "k_rope": kr_new,
+                             "slot_pos": cur_pos, "write_idx": write_idx}
+
     n_slots = cache["latent"].shape[1]
     write_idx = cur_pos % n_slots
     bidx = jnp.arange(B)
@@ -379,18 +466,10 @@ def apply_mla_step(cfg, params, x, *, cache, cur_pos, ctx, **_):
         k_rope_new[:, 0].astype(cache["k_rope"].dtype))
     slot_pos = cache["slot_pos"].at[bidx, write_idx].set(cur_pos)
 
-    # absorb q through the key half of wkv_b: (B,H,nope) -> (B,H,kv_lora)
-    wkv_b = params["wkv_b"].reshape(
-        m.kv_lora_rank, H, m.qk_nope_head_dim + m.v_head_dim)
-    w_bk = wkv_b[:, :, :m.qk_nope_head_dim]                  # (lora,H,nope)
-    w_bv = wkv_b[:, :, m.qk_nope_head_dim:]                  # (lora,H,v)
-    q_lat = jnp.einsum("bhn,lhn->bhl", q_nope, w_bk)         # (B,H,lora)
-
     # MQA over the latent cache: KV=1, G=H, hd = lora + rope
     q_cat = jnp.concatenate([q_lat, q_rope], -1)[:, None, :, :]  # (B,1,H,hd)
     k_cat = jnp.concatenate([latent_c, krope_c], -1)[:, :, None, :]
     v_lat = latent_c[:, :, None, :]                          # (B,S,1,lora)
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
     if ctx is not None and ctx.decode_attn == "flash_decode":
         out = flash_decode.flash_decode(q_cat, k_cat, v_lat, slot_pos, cur_pos,
                                         window=None, softmax_scale=scale,
@@ -399,8 +478,5 @@ def apply_mla_step(cfg, params, x, *, cache, cur_pos, ctx, **_):
         out = decode_attend(q_cat, k_cat, v_lat,
                             slot_pos[0] if slot_pos.ndim == 2 else slot_pos,
                             cur_pos, window=None, softmax_scale=scale)
-    out_lat = out.reshape(B, H, m.kv_lora_rank)
-    out = jnp.einsum("bhl,lhv->bhv", out_lat, w_bv)          # expand to v-space
-    y = out.reshape(B, H * m.v_head_dim) @ params["wo"]
     new_cache = dict(cache, latent=latent_c, k_rope=krope_c, slot_pos=slot_pos)
-    return x + y, new_cache
+    return expand(out), new_cache

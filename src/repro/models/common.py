@@ -40,6 +40,22 @@ class ParallelCtx:
         return self.mesh.shape[self.tp]
 
 
+@dataclasses.dataclass(frozen=True)
+class CommittedCache:
+    """A decode cache that a step reads and does not write.
+
+    Given a layer's or a stage's cache in this type, a decode step returns
+    the token's *slot* in its place (what it would have written: the new
+    key/value or latent, its position and the write index; a recurrent
+    layer's whole new state) instead of a new cache, and the cache's owner
+    writes the slot itself (:func:`repro.models.write_decode_slots`)."""
+    cache: object
+
+
+jax.tree_util.register_dataclass(CommittedCache, data_fields=["cache"],
+                                 meta_fields=[])
+
+
 def shard(x, ctx: Optional[ParallelCtx], *spec):
     """Apply a sharding constraint if running distributed."""
     if ctx is None:

@@ -13,6 +13,8 @@ init_params(cfg, key)                  -> params pytree
 forward(cfg, params, inputs, ...)      -> ExitsOut (train / prefill)
 decode_step(cfg, params, cache, ...)   -> (exits, new_cache)
 DecodeFrom(h, stage)                   -> decode_step's resume carry
+CommittedCache(stage_cache)            -> a stage decode_step reads only
+write_decode_slots(caches, slots)      -> caches with the steps' slots written
 init_decode_cache(cfg, batch, slots)   -> cache pytree
 stage_forward / stage_decode_step      -> the scheduler's dispatch unit
 count_params_analytic(cfg)             -> N (for roofline MODEL_FLOPS)
@@ -27,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention, exits, ffn, moe, ssm, xlstm
-from repro.models.common import KeyGen, dense_init, param_dtype, shard
+from repro.models.common import (CommittedCache, KeyGen, dense_init,
+                                 param_dtype, shard)
 
 FEATURE_DIM = 32  # input feature width for the "features" modality
 
@@ -120,6 +123,8 @@ def apply_layer(cfg, sig: Sig, params, h, *, mode, cache=None,
     """Returns (h, cache_out, aux)."""
     aux = jnp.zeros((), jnp.float32)
     local = sig.kind == "attn_local"
+    if isinstance(cache, CommittedCache) and not sig.kind.startswith("attn"):
+        cache = cache.cache     # a recurrent state's slot is its new state
     if sig.kind in ("attn", "attn_local"):
         if mode == "step":
             if cfg.attention == "mla":
@@ -493,9 +498,16 @@ def decode_step(cfg, params, cache, token, cur_pos, *, ctx=None,
     ``token.stage`` are skipped, so a step of depth d+1 continues depth
     d's step from its ``h_final`` and new cache.  cur_pos: (B,) int32.
     Returns (ExitsOut with last-position logits per stage run, new_cache).
-    From a token, new_cache forwards the stages not run; a resumed step
-    returns None in their place, for the caller holds them already: a
-    jitted output that is an unchanged input is a full copy on the device.
+    From a token, new_cache forwards the plain stages not run; a resumed
+    step, and a :class:`CommittedCache` stage not run, give None in their
+    place, for the caller holds them already: a jitted output that is an
+    unchanged input is a full copy on the device.
+
+    A stage given as a :class:`CommittedCache` is read and not written:
+    its entry of new_cache is the token's slot, the stage's cache
+    structure with each layer's slot in place of its cache (what
+    :func:`write_decode_slots` writes), and no output is as large as a
+    layer's cache.
     """
     layouts = stage_layouts(cfg)
     n_stages = len(layouts) if upto_stage is None else upto_stage
@@ -504,7 +516,8 @@ def decode_step(cfg, params, cache, token, cur_pos, *, ctx=None,
         new_cache = [None] * len(layouts)
     else:
         first, h = 0, embed_one(cfg, params["embed"], token, cur_pos)
-        new_cache = list(cache)
+        new_cache = [None if isinstance(c, CommittedCache) else c
+                     for c in cache]
     if ctx is not None:
         h = shard(h, ctx, ctx.dp, None)                      # (B, d)
     logits_list, conf_list = [], []
@@ -527,9 +540,15 @@ def decode_step(cfg, params, cache, token, cur_pos, *, ctx=None,
 
 def _stage_decode(cfg, stage_params, lay: StageLayout, st_cache, h, cur_pos,
                   ctx):
+    """One stage of a decode step: (h, the stage's new cache), or its
+    slots where ``st_cache`` is a :class:`CommittedCache`."""
+    wrap = lambda c: c
+    if isinstance(st_cache, CommittedCache):
+        st_cache, wrap = st_cache.cache, CommittedCache
+
     def one(idx, p, c, h):
         h, c_new, _ = apply_layer(cfg, layer_sig(cfg, idx), p, h, mode="step",
-                                  cache=c, cur_pos=cur_pos, ctx=ctx)
+                                  cache=wrap(c), cur_pos=cur_pos, ctx=ctx)
         return h, c_new
 
     new_cache: dict = {"prefix": [], "scan": None, "tail": []}
@@ -545,7 +564,8 @@ def _stage_decode(cfg, stage_params, lay: StageLayout, st_cache, h, cur_pos,
             cs = []
             for sig, p, c in zip(sigs, period_params, period_cache):
                 h, c_new, _ = apply_layer(cfg, sig, p, h, mode="step",
-                                          cache=c, cur_pos=cur_pos, ctx=ctx)
+                                          cache=wrap(c), cur_pos=cur_pos,
+                                          ctx=ctx)
                 cs.append(c_new)
             return h, tuple(cs)
 
@@ -557,6 +577,33 @@ def _stage_decode(cfg, stage_params, lay: StageLayout, st_cache, h, cur_pos,
         h, c_new = one(i, p, c, h)
         new_cache["tail"].append(c_new)
     return h, new_cache
+
+
+def _write_layer(cache, slot):
+    if "write_idx" not in slot:       # a recurrent layer: its new state
+        return slot
+    rows = jnp.arange(slot["write_idx"].shape[0])
+    return {k: x.at[rows, slot["write_idx"]].set(slot[k].astype(x.dtype))
+            for k, x in cache.items()}
+
+
+def write_decode_slots(st_caches, st_slots):
+    """Stage caches with the slots that steps on their
+    :class:`CommittedCache` returned written in: ``st_caches`` and
+    ``st_slots`` pair stage by stage.  Jitted with the caches donated,
+    the write is in place."""
+    out = []
+    for c, s in zip(st_caches, st_slots):
+        st = {"prefix": [_write_layer(a, b)
+                         for a, b in zip(c["prefix"], s["prefix"])],
+              "scan": None,
+              "tail": [_write_layer(a, b)
+                       for a, b in zip(c["tail"], s["tail"])]}
+        if c["scan"] is not None:
+            st["scan"] = jax.vmap(lambda cs, ss: tuple(map(
+                _write_layer, cs, ss)))(c["scan"], s["scan"])
+        out.append(st)
+    return out
 
 
 # ---------------------------------------------------------------------------

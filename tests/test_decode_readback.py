@@ -65,7 +65,8 @@ def test_commit_reads_the_steps_own_confidence(decode_parts, depth,
             out, _cache = ex.chosen
             assert len(out.confidences) == stage + 1
             assert abs(got - float(jnp.mean(out.confidences[-1]))) <= 1e-6
-        out, ex.cache = ex.chosen
+        out, slots = ex.chosen
+        ex.commit_slots(slots)
         ex.tok = jnp.argmax(out.logits[-1], -1).astype(jnp.int32)
     assert ex.readbacks == N_TOKENS * depth
     if not speculate:
